@@ -42,12 +42,13 @@ def run(print_fn=print):
     # pallas interpret-mode correctness timing (not a perf number on CPU)
     tk = timeit(lambda: ops.decode_attention(
         q[:2], k[:2, :256], v[:2, :256], pos[:2, :256],
-        jnp.full((2,), 255, jnp.int32), use_kernel="pallas", block_s=128),
+        jnp.full((2,), 255, jnp.int32), use_kernel="pallas", interpret=True,
+        block_s=128),
         warmup=1, iters=2)
     err = float(jnp.abs(
         ops.decode_attention(q[:2], k[:2, :256], v[:2, :256], pos[:2, :256],
                              jnp.full((2,), 255, jnp.int32),
-                             use_kernel="pallas", block_s=128)
+                             use_kernel="pallas", interpret=True, block_s=128)
         - ref.decode_attention_ref(q[:2], k[:2, :256], v[:2, :256],
                                    pos[:2, :256],
                                    jnp.full((2,), 255, jnp.int32))).max())

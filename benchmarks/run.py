@@ -56,6 +56,8 @@ def main() -> None:
                          "JSON artifacts, fail unless EVERY bench module "
                          "runs clean (ok: true) and emits rows")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         # must be set before bench modules import/run (common.smoke())
         os.environ["BENCH_SMOKE"] = "1"

@@ -1,9 +1,11 @@
 """Jit'd dispatch wrappers for the R-Part attention kernels.
 
 ``use_kernel='auto'`` picks the Pallas kernel on TPU and the jnp reference
-on CPU (where the kernels are still *validated* via interpret mode, but
-the reference lowers to better XLA/CPU code and keeps the multi-pod
-dry-run free of per-backend custom calls).
+on other backends (the reference lowers to better XLA/CPU code and keeps
+the multi-pod dry-run free of per-backend custom calls).
+``use_kernel='pallas'`` forces the kernel; off the TPU it runs only with
+``interpret=True``, which the CPU tests pass to validate the kernels —
+it is never on by default.
 """
 from __future__ import annotations
 
@@ -17,25 +19,23 @@ from repro.kernels import quant_kv as _qk
 from repro.kernels import ref as _ref
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def _use_pallas(use_kernel: str) -> bool:
+    return use_kernel == "pallas" or (
+        use_kernel == "auto" and jax.default_backend() == "tpu")
 
 
 @partial(jax.jit, static_argnames=("window", "sink", "softcap", "block_s",
                                    "use_kernel", "interpret"))
 def decode_attention(q, k, v, pos, lengths, *, window: int = 0, sink: int = 0,
-                     softcap: float = 0.0, block_s: int = 512,
-                     use_kernel: str = "auto", interpret: bool = True):
+                     softcap: float = 0.0, block_s: int = 2048,
+                     use_kernel: str = "auto", interpret: bool = False):
     """Batched decode attention.  q [B,Hq,Dh]; k,v [B,S,Hkv,Dh];
     pos [B,S] int32; lengths [B] int32 -> [B,Hq,Dh]."""
-    if use_kernel == "pallas" or (use_kernel == "auto" and _on_tpu()):
+    if _use_pallas(use_kernel):
         return _da.decode_attention(q, k, v, pos, lengths, window=window,
                                     sink=sink, softcap=softcap,
                                     block_s=block_s,
-                                    interpret=interpret and not _on_tpu())
+                                    interpret=interpret)
     return _ref.decode_attention_ref(q, k, v, pos, lengths, window=window,
                                      sink=sink, softcap=softcap)
 
@@ -44,13 +44,13 @@ def decode_attention(q, k, v, pos, lengths, *, window: int = 0, sink: int = 0,
                                    "use_kernel", "interpret"))
 def decode_attention_int8(q, k_q, k_scale, v_q, v_scale, pos, lengths, *,
                           window: int = 0, sink: int = 0, softcap: float = 0.0,
-                          block_s: int = 512, use_kernel: str = "auto",
-                          interpret: bool = True):
-    if use_kernel == "pallas" or (use_kernel == "auto" and _on_tpu()):
-        return _qk.decode_attention_int8(
-            q, k_q, k_scale, v_q, v_scale, pos, lengths, window=window,
+                          block_s: int = 2048, use_kernel: str = "auto",
+                          interpret: bool = False):
+    if _use_pallas(use_kernel):
+        return _da.decode_attention(
+            q, k_q, v_q, pos, lengths, k_scale, v_scale, window=window,
             sink=sink, softcap=softcap, block_s=block_s,
-            interpret=interpret and not _on_tpu())
+            interpret=interpret)
     return _ref.decode_attention_int8_ref(
         q, k_q, k_scale, v_q, v_scale, pos, lengths, window=window,
         sink=sink, softcap=softcap)
@@ -61,13 +61,13 @@ def decode_attention_int8(q, k_q, k_scale, v_q, v_scale, pos, lengths, *,
 def paged_decode_attention(q, pages_k, pages_v, tables, lengths, *,
                            window: int = 0, sink: int = 0,
                            softcap: float = 0.0, use_kernel: str = "auto",
-                           interpret: bool = True):
+                           interpret: bool = False):
     """Block-table decode attention.  q [B,Hq,Dh]; pages_k/v
     [P,page,Hkv,Dh]; tables [B,MP] int32; lengths [B] -> [B,Hq,Dh]."""
-    if use_kernel == "pallas" or (use_kernel == "auto" and _on_tpu()):
+    if _use_pallas(use_kernel):
         return _pa.paged_decode_attention(
             q, pages_k, pages_v, tables, lengths, window=window, sink=sink,
-            softcap=softcap, interpret=interpret and not _on_tpu())
+            softcap=softcap, interpret=interpret)
     return _ref.paged_decode_attention_ref(
         q, pages_k, pages_v, tables, lengths, window=window, sink=sink,
         softcap=softcap)
@@ -77,22 +77,21 @@ def paged_decode_attention(q, pages_k, pages_v, tables, lengths, *,
                                    "use_kernel", "interpret"))
 def paged_decode_attention_int8(q, pk_q, pk_s, pv_q, pv_s, tables, lengths,
                                 *, window: int = 0, sink: int = 0,
-                                softcap: float = 0.0, block_s: int = 512,
+                                softcap: float = 0.0, block_s: int = 2048,
                                 use_kernel: str = "auto",
-                                interpret: bool = True):
+                                interpret: bool = False):
     """Int8 pools compose the paged gather with the dense int8 kernel: the
     pages are gathered into a per-sequence slab (with derived positions)
     and the existing quant_kv flash-decode consumes it.  On CPU the whole
     chain stays the jnp reference."""
-    if use_kernel == "pallas" or (use_kernel == "auto" and _on_tpu()):
+    if _use_pallas(use_kernel):
         k_q, pos = _ref.paged_gather(pk_q, tables)
         k_s, _ = _ref.paged_gather(pk_s, tables)
         v_q, _ = _ref.paged_gather(pv_q, tables)
         v_s, _ = _ref.paged_gather(pv_s, tables)
-        return _qk.decode_attention_int8(
-            q, k_q, k_s, v_q, v_s, pos, lengths, window=window, sink=sink,
-            softcap=softcap, block_s=block_s,
-            interpret=interpret and not _on_tpu())
+        return _da.decode_attention(
+            q, k_q, v_q, pos, lengths, k_s, v_s, window=window, sink=sink,
+            softcap=softcap, block_s=block_s, interpret=interpret)
     return _ref.paged_decode_attention_int8_ref(
         q, pk_q, pk_s, pv_q, pv_s, tables, lengths, window=window,
         sink=sink, softcap=softcap)
@@ -109,7 +108,7 @@ def paged_decode_attention_int8(q, pk_q, pk_s, pv_q, pv_s, tables, lengths,
                                    "use_kernel", "interpret"))
 def verify_attention(q, k, v, pos, lengths, *, window: int = 0, sink: int = 0,
                      softcap: float = 0.0, kv_chunk: int = 1024,
-                     use_kernel: str = "auto", interpret: bool = True):
+                     use_kernel: str = "auto", interpret: bool = False):
     """Dense multi-token verify.  q [B,T,Hq,Dh]; k,v [B,S,Hkv,Dh];
     pos [B,S] int32; lengths [B] int32 base -> [B,T,Hq,Dh]."""
     del use_kernel, interpret
@@ -123,7 +122,7 @@ def verify_attention(q, k, v, pos, lengths, *, window: int = 0, sink: int = 0,
 def verify_attention_int8(q, k_q, k_scale, v_q, v_scale, pos, lengths, *,
                           window: int = 0, sink: int = 0, softcap: float = 0.0,
                           kv_chunk: int = 1024, use_kernel: str = "auto",
-                          interpret: bool = True):
+                          interpret: bool = False):
     del use_kernel, interpret
     return _ref.verify_attention_int8_ref(
         q, k_q, k_scale, v_q, v_scale, pos, lengths, window=window,
@@ -135,13 +134,13 @@ def verify_attention_int8(q, k_q, k_scale, v_q, v_scale, pos, lengths, *,
 def paged_verify_attention(q, pages_k, pages_v, tables, lengths, *,
                            window: int = 0, sink: int = 0,
                            softcap: float = 0.0, kv_chunk: int = 1024,
-                           use_kernel: str = "auto", interpret: bool = True):
+                           use_kernel: str = "auto", interpret: bool = False):
     """Block-table multi-token verify.  q [B,T,Hq,Dh]; pages_k/v
     [P,page,Hkv,Dh]; tables [B,MP] int32; lengths [B] base -> [B,T,Hq,Dh]."""
-    if use_kernel == "pallas" or (use_kernel == "auto" and _on_tpu()):
+    if _use_pallas(use_kernel):
         return _pa.paged_verify_attention(
             q, pages_k, pages_v, tables, lengths, window=window, sink=sink,
-            softcap=softcap, interpret=interpret and not _on_tpu())
+            softcap=softcap, interpret=interpret)
     return _ref.paged_verify_attention_ref(
         q, pages_k, pages_v, tables, lengths, window=window, sink=sink,
         softcap=softcap, kv_chunk=kv_chunk)
@@ -153,7 +152,7 @@ def paged_verify_attention_int8(q, pk_q, pk_s, pv_q, pv_s, tables, lengths,
                                 *, window: int = 0, sink: int = 0,
                                 softcap: float = 0.0, kv_chunk: int = 1024,
                                 use_kernel: str = "auto",
-                                interpret: bool = True):
+                                interpret: bool = False):
     """Int8 pools gather into a per-sequence slab (as the decode int8 path
     does) and run the dense int8 verify reference over it."""
     del use_kernel, interpret

@@ -23,12 +23,19 @@ are *derived* — ``k*page + j`` — and need not be stored: the valid mask
 A fully unmapped row (freed slot still being stepped by the engine)
 yields an all-masked score row and a zero output, never a stale read.
 
-Grid: (batch, kv_heads, MP).  The page-list dimension is innermost and
-sequential; the block table and lengths ride in scalar-prefetch SMEM so
-each step's K/V DMA source address is ``tables[b, i]`` — the gather never
+Grid: (batch, MP).  The page-list dimension is innermost and sequential;
+the block table and lengths ride in scalar-prefetch SMEM so each step's
+K/V DMA source address is ``tables[b, i]`` — the gather never
 materializes a contiguous copy of the sequence (the jnp reference in
 kernels/ref.py does exactly that gather, and is the oracle).  Online
-softmax state lives in VMEM scratch as in decode_attention.py.
+softmax state lives in VMEM scratch, shared with decode_attention.py.
+
+TPU tiling: a page is taken with ALL its kv heads, viewing the pool as
+``[P, page*Hkv, Dh]`` (a free reshape), so a block spans whole trailing
+dimensions whatever ``page`` and ``Hkv`` are; the query block holds all
+heads and a head-match mask pairs query and kv heads, as described in
+decode_attention.py.  Row ``j`` of a page block is token ``j // Hkv``
+of kv head ``j % Hkv``.
 
 Shared-prefix aliasing: the kernel makes NO exclusivity assumption about
 page ids — two rows' tables may legally point at the same page (the
@@ -39,6 +46,15 @@ in the allocator's step path (``write_token_paged`` /
 ``r_attention_paged_chunk``), which copy-on-write-clones a shared page
 before any row writes into it — so an aliased page is immutable for as
 long as it is aliased, and no new kernel work is needed for reuse.
+
+Speculative-decode verify scores T queries per sequence against the same
+pages in ONE pool sweep: the T query tokens are stacked as rows
+``[T*Hq, Dh]`` (row ``i`` is token ``i // Hq``, head ``i % Hq``), so every
+page is DMA'd once per row for all T candidates — the per-token cost is
+the KV-bandwidth pass, and verifying k+1 positions amortizes it
+(k+1)-fold.  Query t of row b sits at absolute position
+``lengths[b] + t`` (lengths = token count before the verify step).
+Decode is the T == 1 case of the same kernel.
 """
 from __future__ import annotations
 
@@ -50,186 +66,45 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.decode_attention import (F32, attend_block,
+                                            causal_window_mask, head_match,
+                                            init_scratch, write_output)
 
 
 def _kernel(tbl_ref,            # SMEM [B, MP] int32 block table
-            len_ref,            # SMEM [B] int32 new-token positions
-            q_ref,              # [1, 1, G, Dh]
-            k_ref,              # [1, page, 1, Dh]  (page tables[b, i])
-            v_ref,              # [1, page, 1, Dh]
-            o_ref,              # [1, 1, G, Dh]
-            m_s, l_s, acc,      # VMEM scratch: [G,1], [G,1], [G,Dh] fp32
+            len_ref,            # SMEM [B] int32 base positions
+            q_ref,              # [1, T*Hq, Dh]
+            k_ref, v_ref,       # [1, page*Hkv, Dh]  (page tables[b, i])
+            o_ref,              # [1, T*Hq, Dh]
+            m_s, l_s, acc,      # VMEM scratch: [T*Hq,1], [T*Hq,1], [T*Hq,Dh]
             *, scale: float, window: int, sink: int, softcap: float,
-            page: int, blocks: int):
+            page: int, hq: int, hkv: int, blocks: int):
     bi = pl.program_id(0)
-    sb = pl.program_id(2)
+    sb = pl.program_id(1)
 
     @pl.when(sb == 0)
     def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc[...] = jnp.zeros_like(acc)
+        init_scratch(m_s, l_s, acc)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # [G, Dh]
-    k = k_ref[0, :, 0].astype(jnp.float32)               # [page, Dh]
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    qpos = len_ref[bi]
-    mapped = tbl_ref[bi, sb] >= 0
+    q = q_ref[0].astype(F32) * scale                     # [T*Hq, Dh]
+    k = k_ref[0].astype(F32)                             # [page*Hkv, Dh]
+    v = v_ref[0].astype(F32)
+    shape = (q.shape[0], k.shape[0])
+    qpos = len_ref[bi] + jax.lax.broadcasted_iota(jnp.int32, shape, 0) // hq
     # absolute positions of this page's slots are derived, not stored
-    pos = sb * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)[0]
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [G, page]
-    if softcap > 0.0:
-        s = softcap * jnp.tanh(s / softcap)
-    valid = mapped & (pos <= qpos)
-    if window > 0:
-        in_win = pos > qpos - window
-        if sink > 0:
-            in_win |= pos < sink
-        valid &= in_win
-    s = jnp.where(valid[None, :], s, NEG_INF)
-
-    m_prev = m_s[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc[...] = acc[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_s[...] = m_new
+    kpos = sb * page + jax.lax.broadcasted_iota(jnp.int32, shape, 1) // hkv
+    valid = ((tbl_ref[bi, sb] >= 0) & head_match(*shape, hq, hkv)
+             & causal_window_mask(qpos, kpos, window=window, sink=sink))
+    attend_block(q, k, v, valid, m_s, l_s, acc, softcap=softcap)
 
     @pl.when(sb == blocks - 1)
     def _done():
-        out = acc[...] / jnp.maximum(l_s[...], 1e-30)
-        out = jnp.where(m_s[...] > NEG_INF / 2, out, 0.0)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
-
-
-def paged_decode_attention(q, pages_k, pages_v, tables, lengths, *,
-                           window: int = 0, sink: int = 0,
-                           softcap: float = 0.0, interpret: bool = True):
-    """q [B,Hq,Dh]; pages_k/v [P,page,Hkv,Dh]; tables [B,MP] int32
-    (-1 = unmapped); lengths [B] int32.  Returns o [B,Hq,Dh] in q.dtype."""
-    b, hq, dh = q.shape
-    n_pages, page, hkv, _ = pages_k.shape
-    mp = tables.shape[1]
-    assert hq % hkv == 0, (hq, hkv)
-    g = hq // hkv
-    qg = q.reshape(b, hkv, g, dh)
-
-    # unmapped (-1) entries are masked out by ``mapped`` in the kernel; the
-    # index map clamps them so the DMA source stays in-pool
-    def _page_spec():
-        return pl.BlockSpec(
-            (1, page, 1, dh),
-            lambda bi, hi, si, tbl, ln: (jnp.maximum(tbl[bi, si], 0), 0,
-                                         hi, 0))
-
-    kern = functools.partial(
-        _kernel, scale=1.0 / math.sqrt(dh), window=window, sink=sink,
-        softcap=softcap, page=page, blocks=mp)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hkv, mp),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, dh), lambda bi, hi, si, tbl, ln:
-                         (bi, hi, 0, 0)),
-            _page_spec(),
-            _page_spec(),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dh), lambda bi, hi, si, tbl, ln:
-                               (bi, hi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dh), jnp.float32),
-        ],
-    )
-
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
-        interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      qg, pages_k, pages_v)
-    return out.reshape(b, hq, dh)
-
-
-# ---------------------------------------------------------------------------
-# speculative-decode verify: T queries per sequence against the same paged
-# KV in ONE pool sweep.  This is the whole point of spec decode on the
-# R-side — the per-token cost is the KV-bandwidth pass, and verifying k+1
-# candidate positions amortizes that pass (k+1)-fold.  The layout folds the
-# T query tokens into the head-group dimension ([B, Hkv, T*G, Dh]) so every
-# page is still DMA'd exactly once per (row, kv-head); only the causal mask
-# becomes per-query: query t of row b sits at absolute position
-# ``lengths[b] + t`` (lengths = token count before the verify step), so the
-# mask is ``pos <= lengths[b] + t`` per scratch row.  T == 1 is bit-exact
-# with the decode kernel above.
-# ---------------------------------------------------------------------------
-def _verify_kernel(tbl_ref,         # SMEM [B, MP] int32 block table
-                   len_ref,         # SMEM [B] int32 base positions
-                   q_ref,           # [1, 1, T*G, Dh]
-                   k_ref,           # [1, page, 1, Dh]  (page tables[b, i])
-                   v_ref,           # [1, page, 1, Dh]
-                   o_ref,           # [1, 1, T*G, Dh]
-                   m_s, l_s, acc,   # VMEM scratch: [T*G,1], [T*G,1], [T*G,Dh]
-                   *, scale: float, window: int, sink: int, softcap: float,
-                   page: int, blocks: int, g: int):
-    bi = pl.program_id(0)
-    sb = pl.program_id(2)
-
-    @pl.when(sb == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc[...] = jnp.zeros_like(acc)
-
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # [T*G, Dh]
-    k = k_ref[0, :, 0].astype(jnp.float32)               # [page, Dh]
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    tg = q.shape[0]
-    # scratch row i = query token i // g of head-group lane i % g
-    qt = jax.lax.broadcasted_iota(jnp.int32, (tg, 1), 0) // g
-    qpos = len_ref[bi] + qt                              # [T*G, 1]
-    mapped = tbl_ref[bi, sb] >= 0
-    pos = sb * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)[0]
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [T*G, page]
-    if softcap > 0.0:
-        s = softcap * jnp.tanh(s / softcap)
-    valid = mapped & (pos[None, :] <= qpos)
-    if window > 0:
-        in_win = pos[None, :] > qpos - window
-        if sink > 0:
-            in_win |= (pos < sink)[None, :]
-        valid &= in_win
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_s[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc[...] = acc[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_s[...] = m_new
-
-    @pl.when(sb == blocks - 1)
-    def _done():
-        out = acc[...] / jnp.maximum(l_s[...], 1e-30)
-        out = jnp.where(m_s[...] > NEG_INF / 2, out, 0.0)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        write_output(o_ref, m_s, l_s, acc)
 
 
 def paged_verify_attention(q, pages_k, pages_v, tables, lengths, *,
                            window: int = 0, sink: int = 0,
-                           softcap: float = 0.0, interpret: bool = True):
+                           softcap: float = 0.0, interpret: bool = False):
     """q [B,T,Hq,Dh]; pages_k/v [P,page,Hkv,Dh]; tables [B,MP] int32
     (-1 = unmapped); lengths [B] int32 base positions (query t attends
     positions <= lengths[b] + t).  Returns o [B,T,Hq,Dh] in q.dtype."""
@@ -237,45 +112,44 @@ def paged_verify_attention(q, pages_k, pages_v, tables, lengths, *,
     n_pages, page, hkv, _ = pages_k.shape
     mp = tables.shape[1]
     assert hq % hkv == 0, (hq, hkv)
-    g = hq // hkv
-    # fold tokens into the head-group axis: [B, Hkv, T*G, Dh]
-    qg = q.reshape(b, t, hkv, g, dh).transpose(0, 2, 1, 3, 4) \
-          .reshape(b, hkv, t * g, dh)
+    rows = t * hq
+    qr = q.reshape(b, rows, dh)
+    kr = pages_k.reshape(n_pages, page * hkv, dh)
+    vr = pages_v.reshape(n_pages, page * hkv, dh)
 
-    def _page_spec():
-        return pl.BlockSpec(
-            (1, page, 1, dh),
-            lambda bi, hi, si, tbl, ln: (jnp.maximum(tbl[bi, si], 0), 0,
-                                         hi, 0))
+    # unmapped (-1) entries are masked out in the kernel; the index map
+    # clamps them so the DMA source stays in-pool
+    page_spec = pl.BlockSpec(
+        (1, page * hkv, dh),
+        lambda bi, si, tbl, ln: (jnp.maximum(tbl[bi, si], 0), 0, 0))
+    q_spec = pl.BlockSpec((1, rows, dh), lambda bi, si, tbl, ln: (bi, 0, 0))
 
     kern = functools.partial(
-        _verify_kernel, scale=1.0 / math.sqrt(dh), window=window, sink=sink,
-        softcap=softcap, page=page, blocks=mp, g=g)
-
+        _kernel, scale=1.0 / math.sqrt(dh), window=window, sink=sink,
+        softcap=softcap, page=page, hq=hq, hkv=hkv, blocks=mp)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, mp),
-        in_specs=[
-            pl.BlockSpec((1, 1, t * g, dh), lambda bi, hi, si, tbl, ln:
-                         (bi, hi, 0, 0)),
-            _page_spec(),
-            _page_spec(),
-        ],
-        out_specs=pl.BlockSpec((1, 1, t * g, dh), lambda bi, hi, si, tbl, ln:
-                               (bi, hi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((t * g, 1), jnp.float32),
-            pltpu.VMEM((t * g, 1), jnp.float32),
-            pltpu.VMEM((t * g, dh), jnp.float32),
-        ],
+        grid=(b, mp),
+        in_specs=[q_spec, page_spec, page_spec],
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((rows, 1), F32),
+                        pltpu.VMEM((rows, 1), F32),
+                        pltpu.VMEM((rows, dh), F32)],
     )
-
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, t * g, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows, dh), q.dtype),
         interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      qg, pages_k, pages_v)
-    return out.reshape(b, hkv, t, g, dh).transpose(0, 2, 1, 3, 4) \
-              .reshape(b, t, hq, dh)
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), qr, kr, vr)
+    return out.reshape(b, t, hq, dh)
+
+
+def paged_decode_attention(q, pages_k, pages_v, tables, lengths, *,
+                           window: int = 0, sink: int = 0,
+                           softcap: float = 0.0, interpret: bool = False):
+    """q [B,Hq,Dh]; pages_k/v [P,page,Hkv,Dh]; tables [B,MP] int32
+    (-1 = unmapped); lengths [B] int32.  Returns o [B,Hq,Dh] in q.dtype."""
+    return paged_verify_attention(
+        q[:, None], pages_k, pages_v, tables, lengths, window=window,
+        sink=sink, softcap=softcap, interpret=interpret)[:, 0]

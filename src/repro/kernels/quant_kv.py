@@ -1,25 +1,18 @@
-"""Int8-quantized-KV flash-decode kernel (paper §5.2).
+"""Int8-quantized KV for the flash-decode kernel (paper §5.2).
 
 KV is stored as int8 with one fp32 scale per (token, kv-head) — the
 quantization the paper suggests to quarter R-worker memory traffic.  The
-kernel dequantizes inside VMEM (int8 -> fp32 multiply by scale) and
-otherwise matches decode_attention.py; accumulation stays fp32, so the
-only error source is the storage rounding (bounded in tests).
+kernel is decode_attention.py's, given the scales: it dequantizes in
+VMEM (int8 -> fp32, scales applied to the scores and probabilities);
+accumulation stays fp32, so the only error source is the storage
+rounding (bounded in tests).
 
 Memory traffic per cached token drops from 2·Dh·2B to 2·(Dh·1B + 4B):
 ~3.9x for Dh=128, matching the paper's "~4x speedup or 4x fewer CPUs".
 """
 from __future__ import annotations
 
-import functools
-import math
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-NEG_INF = -1e30
 
 
 # ---------------------------------------------------------------------------
@@ -36,108 +29,3 @@ def quantize_kv(x, axis: int = -1):
 
 def dequantize_kv(q, scale):
     return q.astype(jnp.float32) * scale[..., None]
-
-
-# ---------------------------------------------------------------------------
-# kernel
-# ---------------------------------------------------------------------------
-def _kernel(len_ref, q_ref,
-            k_ref, ks_ref,      # int8 [1,Sblk,1,Dh], fp32 [1,Sblk,1]
-            v_ref, vs_ref,
-            pos_ref, o_ref,
-            m_s, l_s, acc,
-            *, scale: float, window: int, sink: int, softcap: float,
-            blocks: int):
-    sb = pl.program_id(2)
-
-    @pl.when(sb == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc[...] = jnp.zeros_like(acc)
-
-    q = q_ref[0, 0].astype(jnp.float32) * scale                  # [G, Dh]
-    k = k_ref[0, :, 0].astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-    v = v_ref[0, :, 0].astype(jnp.float32) * vs_ref[0, :, 0][:, None]
-    pos = pos_ref[0]
-    qpos = len_ref[0]
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    if softcap > 0.0:
-        s = softcap * jnp.tanh(s / softcap)
-    valid = (pos >= 0) & (pos <= qpos)
-    if window > 0:
-        in_win = pos > qpos - window
-        if sink > 0:
-            in_win |= pos < sink
-        valid &= in_win
-    s = jnp.where(valid[None, :], s, NEG_INF)
-
-    m_prev = m_s[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc[...] = acc[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_s[...] = m_new
-
-    @pl.when(sb == blocks - 1)
-    def _done():
-        out = acc[...] / jnp.maximum(l_s[...], 1e-30)
-        out = jnp.where(m_s[...] > NEG_INF / 2, out, 0.0)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
-
-
-def decode_attention_int8(q, k_q, k_scale, v_q, v_scale, pos, lengths, *,
-                          window: int = 0, sink: int = 0, softcap: float = 0.0,
-                          block_s: int = 512, interpret: bool = True):
-    """q [B,Hq,Dh]; k_q,v_q int8 [B,S,Hkv,Dh]; k_scale,v_scale [B,S,Hkv];
-    pos [B,S]; lengths [B].  Returns [B,Hq,Dh]."""
-    b, hq, dh = q.shape
-    s_len, hkv = k_q.shape[1], k_q.shape[2]
-    g = hq // hkv
-    block_s = min(block_s, pl.next_power_of_2(s_len))
-    blocks = max(1, -(-s_len // block_s))
-    pad = blocks * block_s - s_len
-    if pad:
-        pads4 = ((0, 0), (0, pad), (0, 0), (0, 0))
-        pads3 = ((0, 0), (0, pad), (0, 0))
-        k_q = jnp.pad(k_q, pads4)
-        v_q = jnp.pad(v_q, pads4)
-        k_scale = jnp.pad(k_scale, pads3)
-        v_scale = jnp.pad(v_scale, pads3)
-        pos = jnp.pad(pos, ((0, 0), (0, pad)), constant_values=-1)
-    qg = q.reshape(b, hkv, g, dh)
-
-    kern = functools.partial(
-        _kernel, scale=1.0 / math.sqrt(dh), window=window, sink=sink,
-        softcap=softcap, blocks=blocks)
-
-    out = pl.pallas_call(
-        kern,
-        grid=(b, hkv, blocks),
-        in_specs=[
-            pl.BlockSpec((1,), lambda bi, hi, si: (bi,)),
-            pl.BlockSpec((1, 1, g, dh), lambda bi, hi, si: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, dh),
-                         lambda bi, hi, si: (bi, si, hi, 0)),
-            pl.BlockSpec((1, block_s, 1), lambda bi, hi, si: (bi, si, hi)),
-            pl.BlockSpec((1, block_s, 1, dh),
-                         lambda bi, hi, si: (bi, si, hi, 0)),
-            pl.BlockSpec((1, block_s, 1), lambda bi, hi, si: (bi, si, hi)),
-            pl.BlockSpec((1, block_s), lambda bi, hi, si: (bi, si)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dh),
-                               lambda bi, hi, si: (bi, hi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dh), jnp.float32),
-        ],
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), qg, k_q, k_scale, v_q, v_scale,
-      pos.astype(jnp.int32))
-    return out.reshape(b, hq, dh)

@@ -14,6 +14,7 @@ import jax
 import numpy as np
 
 from repro.core.config import get_arch
+from repro.launch.cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving.engine import ServingEngine
 from repro.serving.request import Request
@@ -38,6 +39,7 @@ def main(argv=None):
     ap.add_argument("--r-workers", type=int, default=2)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

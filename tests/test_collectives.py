@@ -18,7 +18,8 @@ from repro.distributed import sharding as SH
 from repro.distributed.api import use_rules
 from repro.models import model as M
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_arch("granite-3-8b").reduced(layers=2, d_model=64, vocab=128)
 params = M.init_params(jax.random.PRNGKey(0), cfg)
 B, S = 4, 16

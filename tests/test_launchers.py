@@ -48,3 +48,29 @@ def test_dryrun_list():
     lines = [l for l in p.stdout.splitlines() if l.strip()]
     # 39 pairs x 2 meshes x 2 strategies
     assert len(lines) == 39 * 4
+
+
+@pytest.fixture
+def _restore_cache_dir():
+    import jax
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+@pytest.mark.parametrize("env_dir", [None, "/srv/jax-cache"])
+def test_compile_cache_dir(env_dir, monkeypatch, _restore_cache_dir):
+    """An outside JAX_COMPILATION_CACHE_DIR wins and nothing else is set;
+    without one the cache sits at a fixed path in the checkout."""
+    import jax
+    from repro.launch.cache import enable_compile_cache
+    jax.config.update("jax_compilation_cache_dir", None)
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert enable_compile_cache() == os.path.join(ROOT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            ROOT, ".jax_cache")
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert enable_compile_cache() == env_dir
+        assert jax.config.jax_compilation_cache_dir is None
