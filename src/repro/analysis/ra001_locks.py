@@ -2,7 +2,7 @@
 
 The stack's threads (S-worker driver, R-worker threads, timer-delayed
 sink posts, fleet hooks) share a handful of lock-owning classes
-(``CompletionSink``, ``HostTier``, ``MetricsRegistry``, ``SpanTracer``,
+(``CompletionSink``, ``HostTier``, ``MetricsRegistry``,
 ``FaultPlan``).  Correctness rests on two properties nothing else
 checks statically:
 

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.lockwitness import make_lock
+from repro.obs import spans as S
 
 def _quiet_donation_jit(f, donate_argnums):
     """jax.jit with donated dead inputs, suppressing the one expected
@@ -368,10 +369,6 @@ class RWorker(threading.Thread):
         self.outq: "queue.Queue" = queue.Queue()  # legacy (FIFO) replies
         self._jit_cache: Dict[Tuple[str, int], Any] = {}
         self.busy_time = 0.0
-        # obs.SpanTracer (or None): busy windows recorded per _run_one —
-        # set via HeteroPipelineEngine.attach_tracer, never constructed
-        # here so the hot path stays observability-free by default
-        self.tracer = None
         self._killed = False
         # chaos.FaultPlan (or None): fault-injection hooks in _run_one
         # and the paged allocator; a single `is None` test when off
@@ -611,21 +608,23 @@ class RWorker(threading.Thread):
         from repro.serving import paged_cache as PC
         mb = layer // self.cfg.num_layers
         alloc = self.allocators[mb]
-        if layer == self._first_paged_key(mb):
-            act = r_in.get("active")
-            alloc.ensure_lengths(np.asarray(r_in["lengths"]) + 1,
-                                 mask=None if act is None
-                                 else np.asarray(act))
-            # CoW clones computed once on the shared allocator; every
-            # paged layer of this step applies them to its OWN pool
-            # below (the block table already points at the fresh pages)
-            self._step_clones[(mb, "decode")] = alloc.take_clones()
-        clones = self._step_clones.get((mb, "decode"))
-        if clones:
-            self.state[layer] = PC.clone_pool_pages(self.state[layer],
-                                                    clones)
-        r_out, new_pool = self._paged_fn()(r_in, self.state[layer],
-                                           alloc.tables_device())
+        with S.span(S.R_GROW):
+            if layer == self._first_paged_key(mb):
+                act = r_in.get("active")
+                alloc.ensure_lengths(np.asarray(r_in["lengths"]) + 1,
+                                     mask=None if act is None
+                                     else np.asarray(act))
+                # CoW clones computed once on the shared allocator;
+                # every paged layer of this step applies them to its
+                # OWN pool below (the block table already points at
+                # the fresh pages)
+                self._step_clones[(mb, "decode")] = alloc.take_clones()
+            clones = self._step_clones.get((mb, "decode"))
+            if clones:
+                self.state[layer] = PC.clone_pool_pages(self.state[layer],
+                                                        clones)
+            tables = alloc.tables_device()
+        r_out, new_pool = self._paged_fn()(r_in, self.state[layer], tables)
         return r_out, new_pool
 
     def _paged_chunk_fn(self):
@@ -652,22 +651,23 @@ class RWorker(threading.Thread):
         from repro.serving import paged_cache as PC
         mb = layer // self.cfg.num_layers
         alloc = self.allocators[mb]
-        if layer == self._first_paged_key(mb):
-            alloc.append_chunk(np.asarray(r_in["lengths"]),
-                               np.asarray(r_in["valid"]).sum(axis=1))
-            self._step_clones[(mb, "chunk")] = alloc.take_clones()
-            # the prefix bound is invariant until the next table
-            # mutation — scan once per chunk, not once per layer
-            used = int((alloc.tables >= 0).sum(axis=1).max())
-            k = 1
-            while k < used:
-                k *= 2
-            self._chunk_tables[mb] = alloc.tables_device()[
-                :, :min(k, alloc.max_pages)]
-        clones = self._step_clones.get((mb, "chunk"))
-        if clones:
-            self.state[layer] = PC.clone_pool_pages(self.state[layer],
-                                                    clones)
+        with S.span(S.R_GROW):
+            if layer == self._first_paged_key(mb):
+                alloc.append_chunk(np.asarray(r_in["lengths"]),
+                                   np.asarray(r_in["valid"]).sum(axis=1))
+                self._step_clones[(mb, "chunk")] = alloc.take_clones()
+                # the prefix bound is invariant until the next table
+                # mutation — scan once per chunk, not once per layer
+                used = int((alloc.tables >= 0).sum(axis=1).max())
+                k = 1
+                while k < used:
+                    k *= 2
+                self._chunk_tables[mb] = alloc.tables_device()[
+                    :, :min(k, alloc.max_pages)]
+            clones = self._step_clones.get((mb, "chunk"))
+            if clones:
+                self.state[layer] = PC.clone_pool_pages(self.state[layer],
+                                                        clones)
         return self._paged_chunk_fn()(r_in, self.state[layer],
                                       self._chunk_tables[mb])
 
@@ -697,20 +697,21 @@ class RWorker(threading.Thread):
         from repro.serving import paged_cache as PC
         mb = layer // self.cfg.num_layers
         alloc = self.allocators[mb]
-        if layer == self._first_paged_key(mb):
-            alloc.append_chunk(np.asarray(r_in["lengths"]),
-                               np.asarray(r_in["valid"]).sum(axis=1))
-            self._step_clones[(mb, "verify")] = alloc.take_clones()
-            used = int((alloc.tables >= 0).sum(axis=1).max())
-            k = 1
-            while k < used:
-                k *= 2
-            self._chunk_tables[("v", mb)] = alloc.tables_device()[
-                :, :min(k, alloc.max_pages)]
-        clones = self._step_clones.get((mb, "verify"))
-        if clones:
-            self.state[layer] = PC.clone_pool_pages(self.state[layer],
-                                                    clones)
+        with S.span(S.R_GROW):
+            if layer == self._first_paged_key(mb):
+                alloc.append_chunk(np.asarray(r_in["lengths"]),
+                                   np.asarray(r_in["valid"]).sum(axis=1))
+                self._step_clones[(mb, "verify")] = alloc.take_clones()
+                used = int((alloc.tables >= 0).sum(axis=1).max())
+                k = 1
+                while k < used:
+                    k *= 2
+                self._chunk_tables[("v", mb)] = alloc.tables_device()[
+                    :, :min(k, alloc.max_pages)]
+            clones = self._step_clones.get((mb, "verify"))
+            if clones:
+                self.state[layer] = PC.clone_pool_pages(self.state[layer],
+                                                        clones)
         r_in = {k: v for k, v in r_in.items() if k != "verify"}
         return self._paged_verify_fn()(r_in, self.state[layer],
                                        self._chunk_tables[("v", mb)])
@@ -798,14 +799,19 @@ class RWorker(threading.Thread):
             # storage routes to the multi-token verify kernel.
             is_chunk = isinstance(r_in, dict) and "valid" in r_in
             is_verify = is_chunk and "verify" in r_in
-            if layer in self.paged_keys:
-                step = (self._step_paged_verify if is_verify
-                        else self._step_paged_chunk if is_chunk
-                        else self._step_paged)
-                r_out, new_state = step(layer, r_in)
-            else:
-                r_out, new_state = self._fn(kind, phase, chunk=is_chunk)(
-                    r_in, self.state[layer])
+            # tags end in (micro-batch, layer, phase), the args of the
+            # engine's matching repro.pipe.dispatch span
+            t_mb, t_li, t_ph = tag[-3:]
+            with S.span(S.R_KERNEL, mb=t_mb, layer=t_li, phase=t_ph):
+                if layer in self.paged_keys:
+                    step = (self._step_paged_verify if is_verify
+                            else self._step_paged_chunk if is_chunk
+                            else self._step_paged)
+                    r_out, new_state = step(layer, r_in)
+                else:
+                    r_out, new_state = self._fn(
+                        kind, phase, chunk=is_chunk)(r_in,
+                                                     self.state[layer])
             if self.profile_timing or sink is None:
                 # explicit sync for precise timing; the sink path's host
                 # conversion below absorbs it in steady state
@@ -816,7 +822,8 @@ class RWorker(threading.Thread):
                 # host conversion happens HERE, on the worker thread:
                 # transfers overlap across workers and the S-worker
                 # never pays for them
-                host = {k: np.asarray(v) for k, v in r_out.items()}
+                with S.span(S.R_TO_HOST):
+                    host = {k: np.asarray(v) for k, v in r_out.items()}
             dt = time.perf_counter() - t0
             if self.slowdown > 1.0:
                 # simulated heterogeneity: a worker with 1/slowdown
@@ -830,14 +837,6 @@ class RWorker(threading.Thread):
                 time.sleep(extra)
                 dt += extra
             self.busy_time += dt
-            tracer = self.tracer
-            if tracer is not None:
-                # busy window on this worker's own track; dt already
-                # includes the simulated-skew inflation, so stragglers
-                # render as visibly longer spans
-                tracer.add(f"L{layer}.p{phase}", "r-worker",
-                           f"r{self.wid}", t0, t0 + dt,
-                           {"layer": layer, "phase": phase, "kind": kind})
             if sink is None:                     # legacy FIFO reply
                 self.outq.put((tag, r_out))
             elif drop:
@@ -849,8 +848,9 @@ class RWorker(threading.Thread):
             elif dup:
                 # duplicated delivery: the buffer scatter is idempotent,
                 # the collect loop must tolerate the second token
-                sink.post(self.wid, tag, host, self.lo, self.hi)
-                sink.post(self.wid, tag, host, self.lo, self.hi)
+                with S.span(S.R_POST):
+                    sink.post(self.wid, tag, host, self.lo, self.hi)
+                    sink.post(self.wid, tag, host, self.lo, self.hi)
             elif self.sim_deliver_jitter > 0.0:
                 # async delivery over a jittery link: the result lands
                 # late, the worker moves on to its next inbox item
@@ -862,7 +862,8 @@ class RWorker(threading.Thread):
                 t.daemon = True
                 t.start()
             else:
-                sink.post(self.wid, tag, host, self.lo, self.hi)
+                with S.span(S.R_POST):
+                    sink.post(self.wid, tag, host, self.lo, self.hi)
         except Exception as e:  # surface to the S-worker, don't deadlock
             # ship the ORIGINAL exception — traceback intact for the
             # S-side `raise ... from` — plus the failing computation's
@@ -1064,18 +1065,7 @@ class HeteroPipelineEngine:
         self._set_topo()
         self.step_stats: Dict[str, float] = {}
         self.last_step_stats: Dict[str, float] = {}
-        # optional obs.SpanTracer: per-(step, mb, layer, phase) pipeline
-        # spans + worker busy windows; None = zero-cost (one attribute
-        # read per step).  Attach/detach via attach_tracer.
-        self.tracer = None
         self._step_no = 0
-
-    def attach_tracer(self, tracer) -> None:
-        """Wire (or detach, with ``None``) a span tracer into the
-        dispatch/collect path and every live worker thread."""
-        self.tracer = tracer
-        for w in self.workers:
-            w.tracer = tracer
 
     # -- state loading ------------------------------------------------------
     def load_prefill(self, mb: int, tokens, prompt_lens, enc_feats=None):
@@ -1460,6 +1450,10 @@ class HeteroPipelineEngine:
         Returns list of logits [mb_size, vocab] (list of None when
         chunk-only).
         """
+        with S.span(S.PIPE_STEP):
+            return self._decode_step(tokens_per_mb)
+
+    def _decode_step(self, tokens_per_mb):
         run_decode = tokens_per_mb is not None
         if run_decode:
             assert len(tokens_per_mb) == self.num_mb
@@ -1468,12 +1462,8 @@ class HeteroPipelineEngine:
                  "r_wait_s": 0.0, "ooo_advances": 0.0, "prefill_s": 0.0,
                  "dup_completion_count": 0.0}
         t_step0 = pc()
-        tracer = self.tracer
         step_no = self._step_no
         self._step_no += 1
-        # dispatch timestamps for span reconstruction (tracer only):
-        # span = dispatch enqueue -> last worker completion for that tag
-        disp_t: Dict[Tuple[int, int, int], float] = {}
         sink = self._sink
         self._parity ^= 1
         parity, epoch = self._parity, sink.epoch
@@ -1498,26 +1488,27 @@ class HeteroPipelineEngine:
         active = (self.num_mb if run_decode else 0) + len(works)
 
         def dispatch(mb: int, li: int, phase: int, shards) -> None:
-            t0 = pc()
-            tag = (epoch, parity, mb, li, phase)
-            pending[(mb, li, phase)] = {w.wid for w in self.workers}
-            issue_seq[(mb, li, phase)] = len(issue_seq)
-            if self.schedule == "fifo" and mb < self.num_mb:
-                # chunk work is exempt from FIFO pinning: it has no
-                # emission-order contract, it fills bubbles
-                fifo.append((mb, li, phase))
-            kind, _ = self.layers[li]
-            real_mb = mb if mb < self.num_mb else works[mb - self.num_mb].mb
-            if mb >= self.num_mb and works[mb - self.num_mb].verify:
-                # mark verify shards so the R-worker routes them to the
-                # multi-token verify op (key presence, like "valid")
-                shards = tuple(dict(s, verify=True) for s in shards)
-            lkey = self._lkey(real_mb, li)
-            for w, shard in zip(self.workers, shards):
-                w.inq.put((tag, lkey, kind, phase, shard, sink))
-            stats["dispatch_s"] += pc() - t0
-            if tracer is not None:
-                disp_t[(mb, li, phase)] = t0
+            with S.span(S.PIPE_DISPATCH, mb=mb, layer=li, phase=phase):
+                t0 = pc()
+                tag = (epoch, parity, mb, li, phase)
+                pending[(mb, li, phase)] = {w.wid for w in self.workers}
+                issue_seq[(mb, li, phase)] = len(issue_seq)
+                if self.schedule == "fifo" and mb < self.num_mb:
+                    # chunk work is exempt from FIFO pinning: it has no
+                    # emission-order contract, it fills bubbles
+                    fifo.append((mb, li, phase))
+                kind, _ = self.layers[li]
+                real_mb = (mb if mb < self.num_mb
+                           else works[mb - self.num_mb].mb)
+                if mb >= self.num_mb and works[mb - self.num_mb].verify:
+                    # mark verify shards so the R-worker routes them to
+                    # the multi-token verify op (key presence, like
+                    # "valid")
+                    shards = tuple(dict(s, verify=True) for s in shards)
+                lkey = self._lkey(real_mb, li)
+                for w, shard in zip(self.workers, shards):
+                    w.inq.put((tag, lkey, kind, phase, shard, sink))
+                stats["dispatch_s"] += pc() - t0
 
         def advance(mb: int, li: int, phase: int) -> None:
             nonlocal active
@@ -1527,31 +1518,37 @@ class HeteroPipelineEngine:
             me = issue_seq[(mb, li, phase)]
             if any(issue_seq[t] < me for t in pending):
                 stats["ooo_advances"] += 1.0
-            t0 = pc()
-            r_out = sink.gather((epoch, parity, mb, li, phase))
-            t1 = pc()
-            stats["collect_s"] += t1 - t0
-            fn, mode = self._step_fn(li, phase)
-            p = self.layers[li][1]
-            if mode == "phase":
-                carry, shards = fn(p, carries[mb], r_out,
-                                   self.mb_lengths[mb], self.mb_active[mb])
-                carries[mb] = carry
+            with S.span(S.PIPE_GATHER):
+                t0 = pc()
+                r_out = sink.gather((epoch, parity, mb, li, phase))
+                stats["collect_s"] += pc() - t0
+            with S.span(S.PIPE_ADVANCE):
+                t1 = pc()
+                fn, mode = self._step_fn(li, phase)
+                p = self.layers[li][1]
+                if mode == "phase":
+                    carry, shards = fn(p, carries[mb], r_out,
+                                       self.mb_lengths[mb],
+                                       self.mb_active[mb])
+                    carries[mb] = carry
+                    nxt = (li, phase + 1)
+                elif mode == "fused":
+                    carry, shards, new_s = fn(
+                        p, self.layers[li + 1][1], carries[mb], r_out,
+                        self.s_states[mb][li + 1], self.mb_lengths[mb],
+                        self.mb_active[mb])
+                    carries[mb] = carry
+                    self.s_states[mb][li + 1] = new_s
+                    nxt = (li + 1, 0)
+                else:
+                    logits_out[mb] = fn(self.params, p, carries[mb], r_out,
+                                        self.mb_lengths[mb],
+                                        self.mb_active[mb])
+                    nxt = None
                 stats["s_dispatch_s"] += pc() - t1
-                dispatch(mb, li, phase + 1, shards)
-            elif mode == "fused":
-                carry, shards, new_s = fn(
-                    p, self.layers[li + 1][1], carries[mb], r_out,
-                    self.s_states[mb][li + 1], self.mb_lengths[mb],
-                    self.mb_active[mb])
-                carries[mb] = carry
-                self.s_states[mb][li + 1] = new_s
-                stats["s_dispatch_s"] += pc() - t1
-                dispatch(mb, li + 1, 0, shards)
+            if nxt is not None:
+                dispatch(mb, *nxt, shards)
             else:
-                logits_out[mb] = fn(self.params, p, carries[mb], r_out,
-                                    self.mb_lengths[mb], self.mb_active[mb])
-                stats["s_dispatch_s"] += pc() - t1
                 # when this micro-batch's token becomes emittable — the
                 # streaming-latency metric the OoO schedule improves
                 # (FIFO holds a ready micro-batch behind the head)
@@ -1570,53 +1567,59 @@ class HeteroPipelineEngine:
             free_ride = (sink.q.empty()
                          or all(lg is not None for lg in logits_out))
             t0 = pc()
-            r_out = sink.gather((epoch, parity, vmb, li, phase))
-            fn, mode = self._chunk_step_fn(li, phase, wk.tokens.shape[1],
-                                           verify=wk.verify)
-            p = self.layers[li][1]
-            if mode == "phase":
-                carry, shards = fn(p, chunk_carries[vmb], r_out,
+            with S.span(S.PIPE_GATHER):
+                r_out = sink.gather((epoch, parity, vmb, li, phase))
+            with S.span(S.PIPE_ADVANCE):
+                fn, mode = self._chunk_step_fn(li, phase,
+                                               wk.tokens.shape[1],
+                                               verify=wk.verify)
+                p = self.layers[li][1]
+                if mode == "phase":
+                    carry, shards = fn(p, chunk_carries[vmb], r_out,
+                                       wk.base, wk.valid)
+                    chunk_carries[vmb] = carry
+                    nxt = (li, phase + 1)
+                elif mode == "fused":
+                    carry, shards, new_s = fn(
+                        p, self.layers[li + 1][1], chunk_carries[vmb],
+                        r_out, self.s_states[wk.mb][li + 1], wk.base,
+                        wk.valid)
+                    chunk_carries[vmb] = carry
+                    self.s_states[wk.mb][li + 1] = new_s
+                    nxt = (li + 1, 0)
+                else:
+                    wk.logits = fn(self.params, p, chunk_carries[vmb], r_out,
                                    wk.base, wk.valid)
-                chunk_carries[vmb] = carry
+                    nxt = None
                 if free_ride:
                     stats["prefill_s"] += pc() - t0
-                dispatch(vmb, li, phase + 1, shards)
-            elif mode == "fused":
-                carry, shards, new_s = fn(
-                    p, self.layers[li + 1][1], chunk_carries[vmb], r_out,
-                    self.s_states[wk.mb][li + 1], wk.base, wk.valid)
-                chunk_carries[vmb] = carry
-                self.s_states[wk.mb][li + 1] = new_s
-                if free_ride:
-                    stats["prefill_s"] += pc() - t0
-                dispatch(vmb, li + 1, 0, shards)
+            if nxt is not None:
+                dispatch(vmb, *nxt, shards)
             else:
-                wk.logits = fn(self.params, p, chunk_carries[vmb], r_out,
-                               wk.base, wk.valid)
-                if free_ride:
-                    stats["prefill_s"] += pc() - t0
                 active -= 1
 
         for mb in range(self.num_mb if run_decode else 0):
-            t0 = pc()
-            carry, shards, new_s = self._start_fn(0)(
-                self.params, self.layers[0][1], tokens_per_mb[mb],
-                self.s_states[mb][0], self.mb_lengths[mb],
-                self.mb_active[mb])
-            carries[mb] = carry
-            self.s_states[mb][0] = new_s
-            stats["s_dispatch_s"] += pc() - t0
+            with S.span(S.PIPE_START):
+                t0 = pc()
+                carry, shards, new_s = self._start_fn(0)(
+                    self.params, self.layers[0][1], tokens_per_mb[mb],
+                    self.s_states[mb][0], self.mb_lengths[mb],
+                    self.mb_active[mb])
+                carries[mb] = carry
+                self.s_states[mb][0] = new_s
+                stats["s_dispatch_s"] += pc() - t0
             dispatch(mb, 0, 0, shards)
 
         for wk in works:
-            t0 = pc()
-            carry, shards, new_s = self._chunk_start_fn(
-                wk.tokens.shape[1])(
-                self.params, self.layers[0][1], wk.tokens,
-                self.s_states[wk.mb][0], wk.base, wk.valid)
-            chunk_carries[wk.vmb] = carry
-            self.s_states[wk.mb][0] = new_s
-            stats["prefill_s"] += pc() - t0
+            with S.span(S.PIPE_START):
+                t0 = pc()
+                carry, shards, new_s = self._chunk_start_fn(
+                    wk.tokens.shape[1])(
+                    self.params, self.layers[0][1], wk.tokens,
+                    self.s_states[wk.mb][0], wk.base, wk.valid)
+                chunk_carries[wk.vmb] = carry
+                self.s_states[wk.mb][0] = new_s
+                stats["prefill_s"] += pc() - t0
             dispatch(wk.vmb, 0, 0, shards)
 
         # suspicion-based stall detection: poll the sink in short slices
@@ -1632,17 +1635,21 @@ class HeteroPipelineEngine:
         last_progress = pc()
         try:
             while active:
-                t0 = pc()
-                try:
-                    wid, tag, err = sink.q.get(timeout=poll_s)
-                except queue.Empty:
-                    stats["r_wait_s"] += pc() - t0
+                with S.span(S.PIPE_R_WAIT):
+                    t0 = pc()
+                    try:
+                        got = sink.q.get(timeout=poll_s)
+                    except queue.Empty:
+                        got = None
+                    t1 = pc()
+                wait = t1 - t0
+                stats["r_wait_s"] += wait
+                if got is None:
                     self._check_stall(pending, works, strikes,
                                       pc() - last_progress, step_no)
                     continue
-                last_progress = pc()
-                wait = last_progress - t0
-                stats["r_wait_s"] += wait
+                last_progress = t1
+                wid, tag, err = got
                 if works and all(lg is not None for lg in logits_out):
                     # every decode micro-batch has already emitted: this
                     # wait served ONLY chunk work — bill it to prefill
@@ -1681,13 +1688,6 @@ class HeteroPipelineEngine:
                 if outstanding:
                     continue
                 del pending[(mb, li, phase)]
-                if tracer is not None:
-                    track = (f"mb{mb}" if mb < self.num_mb
-                             else f"prefill-vmb{mb - self.num_mb}")
-                    tracer.add(f"L{li}.p{phase}", "r-rtt", track,
-                               disp_t.pop((mb, li, phase), t0), pc(),
-                               {"step": step_no, "mb": mb, "layer": li,
-                                "phase": phase})
                 if mb >= self.num_mb:
                     advance_chunk(mb, li, phase)
                 elif self.schedule == "fifo":
@@ -1727,12 +1727,6 @@ class HeteroPipelineEngine:
             self.prefill_results.append(wk)
         stats["step_s"] = pc() - t_step0
         stats["emit_mean_s"] = sum(emit_at) / self.num_mb
-        if tracer is not None:
-            # the enclosing step span — every r-rtt span of this step
-            # nests inside it (the trace test's invariant)
-            tracer.add(f"step {step_no}", "step", "s-worker", t_step0,
-                       t_step0 + stats["step_s"],
-                       {"step": step_no, "prefill_chunks": len(works)})
         self.last_step_stats = stats
         for k, v in stats.items():
             self.step_stats[k] = self.step_stats.get(k, 0.0) + v
@@ -2177,8 +2171,6 @@ class HeteroPipelineEngine:
                     lk, lo, hi, old_spans, exports[lk], lost))
         self.workers = workers
         self.slices = new_slices
-        for w in workers:            # keep span capture across topology
-            w.tracer = self.tracer   # changes (worker list may be new)
         self._set_topo()
         return moved * self.num_mb
 

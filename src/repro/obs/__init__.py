@@ -1,8 +1,8 @@
 """Unified observability: metrics registry, request lifecycle tracing,
-pipeline span export, and perfmodel drift detection.
+and perfmodel drift detection.
 
 One :class:`Observability` object per ``ServingEngine`` bundles the
-four surfaces; everything is off by default and cheap when off (the
+three surfaces; everything is off by default and cheap when off (the
 engine holds ``obs = None`` and every hook is a single ``is None``
 test).  Enable with ``ServingEngine(..., observability=True)`` or pass
 an :class:`ObsConfig` to tune the parts individually.
@@ -11,8 +11,10 @@ an :class:`ObsConfig` to tune the parts individually.
                         backend="hetero", observability=True)
     ...
     eng.metrics()                  # one flat schema-conformant snapshot
-    eng.export_trace("trace.json") # Perfetto-loadable pipeline spans
     print(eng.drift_report())      # measured vs perfmodel-predicted
+
+The hot path's named spans (``repro.obs.spans``) need no switch: they
+are recorded whenever a ``jax.profiler`` trace is taken.
 """
 from __future__ import annotations
 
@@ -22,13 +24,12 @@ from typing import Optional, Union
 from repro.obs.registry import Counter, Gauge, Histogram, MetricsRegistry
 from repro.obs.schema import (LEGACY_ALIASES, StatsDict, assert_conforms,
                               check_key, normalize)
-from repro.obs.spans import SpanTracer
 from repro.obs.drift import DriftMonitor, DriftRecord, DriftReport
 from repro.obs import timeline
 
 __all__ = [
     "ObsConfig", "Observability", "MetricsRegistry", "Counter", "Gauge",
-    "Histogram", "SpanTracer", "DriftMonitor", "DriftRecord", "DriftReport",
+    "Histogram", "DriftMonitor", "DriftRecord", "DriftReport",
     "StatsDict", "assert_conforms", "check_key", "normalize",
     "LEGACY_ALIASES", "timeline", "coerce_obs_config",
 ]
@@ -37,9 +38,7 @@ __all__ = [
 @dataclass
 class ObsConfig:
     timeline: bool = True            # per-request lifecycle events
-    spans: bool = True               # pipeline span tracer
     drift: bool = True               # perfmodel drift monitor
-    span_ring: int = 65536           # max retained spans
     drift_warmup_steps: int = 2      # JIT-compile steps excluded outright
     drift_calibration_steps: int = 20
     drift_tolerance: float = 0.5     # |rel residual| that flags a key
@@ -60,14 +59,12 @@ def coerce_obs_config(
 
 
 class Observability:
-    """Registry + tracer + drift monitor + the pre-bound serving
+    """Registry + drift monitor + the pre-bound serving
     histograms the engine's hot path observes into."""
 
     def __init__(self, cfg: Optional[ObsConfig] = None):
         self.cfg = cfg or ObsConfig()
         self.registry = MetricsRegistry()
-        self.tracer: Optional[SpanTracer] = (
-            SpanTracer(ring=self.cfg.span_ring) if self.cfg.spans else None)
         self.drift: Optional[DriftMonitor] = None   # engine wires this
         r = self.registry
         # serving-level latency histograms (seconds)

@@ -1,94 +1,88 @@
-"""Ring-buffer pipeline span tracer with Chrome trace-event export.
+"""Named host spans of the serving hot path, on the profiler's clock.
 
-The hetero decode loop records one span per (step, micro-batch, layer,
-phase) R-Part round trip (dispatch -> last worker completion), one per
-fused S-worker transition, and one per decode step; R-worker threads
-add their busy windows.  Spans live in a bounded deque — a long
-serving run keeps the most recent ``ring`` spans and counts what it
-dropped, never growing without bound.
+Every boundary of the decode hot path is a ``jax.profiler``
+annotation with one of the fixed names below.  Under
+``jax.profiler.trace(dir, create_perfetto_trace=True)`` (or
+``start_trace``/``stop_trace``) they land in the same profile as the
+device ops, on one clock, so a device idle gap can be laid against the
+host work that was running; with no profile being taken an annotation
+costs about a microsecond.
 
-``export(path)`` writes the Chrome trace-event JSON format
-(``{"traceEvents": [...]}``, ``ph: "X"`` complete events with
-microsecond ``ts``/``dur``), loadable in Perfetto / ``chrome://tracing``
-so OoO bubbles and straggler stalls are visually inspectable.
+Engine thread (``ServingEngine.step`` and the hetero ``decode_step``):
 
-``add`` is the hot-path call: one perf_counter subtraction already done
-by the caller, a tuple allocation, and a lock-guarded deque append.
+- ``repro.step`` — one serving step (a step annotation, ``step_num``);
+- ``repro.step.admit`` — admission and queuing of prefill chunks;
+- ``repro.step.sample`` — sampling the step's logits (its host sync);
+- ``repro.step.emit`` — appending tokens, finishing rows;
+- ``repro.step.prefill_results`` — chunks whose prompt completed;
+- ``repro.pipe.step`` — ``HeteroPipelineEngine.decode_step``;
+- ``repro.pipe.start`` — layer-0 S callables (decode and chunk);
+- ``repro.pipe.r_wait`` — waiting on the completion queue
+  (``hotpath_stats`` ``r_wait_s``);
+- ``repro.pipe.gather`` — assembling a layer's R results
+  (``collect_s``);
+- ``repro.pipe.advance`` — the fused S callable of a layer transition
+  (with ``repro.pipe.start`` of decode rows: ``s_dispatch_s``);
+- ``repro.pipe.dispatch`` — enqueuing R work (``dispatch_s``), args
+  ``mb``, ``layer``, ``phase``.
+
+R-worker threads:
+
+- ``repro.r.kernel`` — the R-Part call, args ``mb``, ``layer``,
+  ``phase`` (the same as its ``repro.pipe.dispatch`` within a step);
+- ``repro.r.grow`` — paged block-table growth (nested in the kernel
+  span: the host sync on lengths, allocator growth, CoW clones, the
+  table upload);
+- ``repro.r.to_host`` — copying ``r_out`` to the host (waits for the
+  kernel);
+- ``repro.r.post`` — delivering the result to the completion sink.
+
+Any thread: ``repro.gc`` — a pause of the garbage collector, from a
+``gc.callbacks`` hook registered once per process on import.
 """
 from __future__ import annotations
 
-import json
-import time
-from collections import deque
-from typing import Dict, List, Optional
+import gc
 
-from repro.analysis.lockwitness import make_lock
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+span = TraceAnnotation
+step_span = StepTraceAnnotation
+
+STEP = "repro.step"
+STEP_ADMIT = "repro.step.admit"
+STEP_SAMPLE = "repro.step.sample"
+STEP_EMIT = "repro.step.emit"
+STEP_PREFILL_RESULTS = "repro.step.prefill_results"
+PIPE_STEP = "repro.pipe.step"
+PIPE_START = "repro.pipe.start"
+PIPE_R_WAIT = "repro.pipe.r_wait"
+PIPE_GATHER = "repro.pipe.gather"
+PIPE_ADVANCE = "repro.pipe.advance"
+PIPE_DISPATCH = "repro.pipe.dispatch"
+R_KERNEL = "repro.r.kernel"
+R_GROW = "repro.r.grow"
+R_TO_HOST = "repro.r.to_host"
+R_POST = "repro.r.post"
+GC = "repro.gc"
+
+NAMES = (STEP, STEP_ADMIT, STEP_SAMPLE, STEP_EMIT, STEP_PREFILL_RESULTS,
+         PIPE_STEP, PIPE_START, PIPE_R_WAIT, PIPE_GATHER, PIPE_ADVANCE,
+         PIPE_DISPATCH, R_KERNEL, R_GROW, R_TO_HOST, R_POST, GC)
+
+# the open collector span; collections never nest (the collector does
+# not re-enter itself), so one slot serves every thread
+_gc_open = []
 
 
-class SpanTracer:
-    def __init__(self, ring: int = 65536):
-        self.t0 = time.perf_counter()
-        self._lock = make_lock("SpanTracer._lock")
-        self._spans = deque(maxlen=max(1, int(ring)))
-        self.added = 0          # lifetime adds; dropped = added - len(spans)
+def _on_gc(phase: str, info) -> None:
+    if phase == "start":
+        a = TraceAnnotation(GC)
+        a.__enter__()
+        _gc_open.append(a)
+    elif _gc_open:
+        _gc_open.pop().__exit__(None, None, None)
 
-    # -- recording --------------------------------------------------------- #
-    def now(self) -> float:
-        return time.perf_counter()
 
-    def add(self, name: str, cat: str, track: str,
-            t_start: float, t_end: float,
-            args: Optional[Dict] = None) -> None:
-        """Record a complete span; ``t_start``/``t_end`` are
-        ``perf_counter`` values (same clock as ``self.t0``)."""
-        with self._lock:
-            self._spans.append((name, cat, track, t_start, t_end, args))
-            self.added += 1
-
-    @property
-    def dropped(self) -> int:
-        with self._lock:
-            return self.added - len(self._spans)
-
-    # -- export ------------------------------------------------------------ #
-    def spans(self) -> List[Dict]:
-        """Spans as dicts (oldest first), for programmatic inspection."""
-        with self._lock:
-            raw = list(self._spans)
-        out = []
-        for name, cat, track, ts, te, args in raw:
-            out.append({"name": name, "cat": cat, "track": track,
-                        "ts_s": ts - self.t0,
-                        "dur_s": max(0.0, te - ts),
-                        "args": args or {}})
-        return out
-
-    def to_chrome(self) -> Dict:
-        """Chrome trace-event JSON object.  Tracks become tids (with
-        ``thread_name`` metadata so Perfetto labels them); ts/dur are
-        microseconds relative to tracer construction."""
-        with self._lock:
-            raw = list(self._spans)
-        tids: Dict[str, int] = {}
-        events: List[Dict] = []
-        for name, cat, track, ts, te, args in raw:
-            tid = tids.setdefault(track, len(tids))
-            ev = {"name": name, "cat": cat, "ph": "X",
-                  "ts": round((ts - self.t0) * 1e6, 3),
-                  "dur": round(max(0.0, te - ts) * 1e6, 3),
-                  "pid": 0, "tid": tid}
-            if args:
-                ev["args"] = args
-            events.append(ev)
-        meta = [{"name": "thread_name", "ph": "M", "pid": 0, "tid": tid,
-                 "args": {"name": track}} for track, tid in tids.items()]
-        meta.append({"name": "process_name", "ph": "M", "pid": 0, "tid": 0,
-                     "args": {"name": "repro serving"}})
-        return {"traceEvents": meta + events,
-                "displayTimeUnit": "ms",
-                "otherData": {"dropped_spans": self.added - len(raw)}}
-
-    def export(self, path: str) -> str:
-        with open(path, "w") as f:
-            json.dump(self.to_chrome(), f)
-        return path
+if _on_gc not in gc.callbacks:
+    gc.callbacks.append(_on_gc)

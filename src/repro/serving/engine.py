@@ -75,6 +75,7 @@ from repro.core import decompose as D
 from repro.core.schedule import LoadController, microbatch_size, w_prime_max
 from repro.models import model as M
 from repro.obs import Observability, coerce_obs_config, schema
+from repro.obs import spans as S
 from repro.obs.drift import DriftMonitor
 from repro.serving.request import Request, Status
 from repro.serving.sampler import sample, spec_accept
@@ -426,7 +427,7 @@ class ServingEngine:
         # unified observability (repro.obs): off by default, and when
         # off every hot-path hook is a single `self.obs is None` test.
         # `observability=True` enables the defaults; pass an ObsConfig
-        # to tune ring sizes / drift calibration.
+        # to tune drift calibration.
         self._obs_obj: Optional[Observability] = None
         self.obs: Optional[Observability] = None
         ocfg = coerce_obs_config(observability)
@@ -457,9 +458,6 @@ class ServingEngine:
                     "pass observability=True|ObsConfig() to enable")
             return
         self.obs = self._obs_obj if on else None
-        if self.backend == "hetero":
-            self.engine.attach_tracer(
-                self._obs_obj.tracer if on else None)
 
     # ------------------------------------------------------------------ #
     def _hetero_init_empty(self, mb: int) -> None:
@@ -1594,6 +1592,10 @@ class ServingEngine:
                 mttr_s=mttr_s)
 
     def step(self) -> StepRecord:
+        with S.step_span(S.STEP, step_num=self.step_idx):
+            return self._step()
+
+    def _step(self) -> StepRecord:
         pc = time.perf_counter
         fleet_wall = prefill_wall = 0.0
         if self.fleet is not None:
@@ -1618,25 +1620,27 @@ class ServingEngine:
                             self.obs.migrated.inc()
         admitted = 0
         t0 = pc()
-        n = self._admit_count()
-        if self.preempt_after and self.paged_kv:
-            # admission pressure: queued work, free slots, but the page
-            # budget said no — after preempt_after such steps, park the
-            # least-finished row so its pages (tier-restorable) make
-            # room; the victim requeues and resumes token-exactly
-            if n == 0 and self.queue and self._free_slots():
-                self._stall_steps += 1
-                if self._stall_steps >= self.preempt_after:
-                    self._auto_preempt()
+        with S.span(S.STEP_ADMIT):
+            n = self._admit_count()
+            if self.preempt_after and self.paged_kv:
+                # admission pressure: queued work, free slots, but the
+                # page budget said no — after preempt_after such steps,
+                # park the least-finished row so its pages (tier-
+                # restorable) make room; the victim requeues and
+                # resumes token-exactly
+                if n == 0 and self.queue and self._free_slots():
+                    self._stall_steps += 1
+                    if self._stall_steps >= self.preempt_after:
+                        self._auto_preempt()
+                        self._stall_steps = 0
+                else:
                     self._stall_steps = 0
-            else:
-                self._stall_steps = 0
-        if n > 0:
-            reqs = [self.queue.popleft() for _ in range(n)]
-            self._place(reqs)
-            admitted = n
-        if self._uses_chunks:
-            self._queue_prefill_chunks()
+            if n > 0:
+                reqs = [self.queue.popleft() for _ in range(n)]
+                self._place(reqs)
+                admitted = n
+            if self._uses_chunks:
+                self._queue_prefill_chunks()
         prefill_wall += pc() - t0
 
         t0 = pc()
@@ -1667,36 +1671,40 @@ class ServingEngine:
                     "prefill_s", 0.0)
                 decode_wall -= min(chunk_s, decode_wall)
                 prefill_wall += chunk_s
-            new_tok = self._sample_tokens(
-                logits, [r if r is not None and r.status is Status.RUNNING
-                         else None for r in self.slots])
+            with S.span(S.STEP_SAMPLE):
+                new_tok = self._sample_tokens(
+                    logits, [r if r is not None
+                             and r.status is Status.RUNNING else None
+                             for r in self.slots])
 
             t_now = pc() if obs is not None else 0.0
             tokens_emitted = 0
-            for i, r in enumerate(self.slots):
-                if r is None or r.status is not Status.RUNNING:
-                    continue        # PREFILLING rows own no decode token
-                tok = int(new_tok[i])
-                r.generated.append(tok)
-                self._last_tok[i] = tok
-                tokens_emitted += 1
-                if obs is not None:
-                    r.mark("token", self.step_idx, t_now)
-                    obs.generated.inc()
-                    prev = self._tok_t[i]
-                    if prev > 0.0:
-                        obs.inter_token.observe(t_now - prev)
-                    self._tok_t[i] = t_now
-                reason = r.finish_reason_for(tok)
-                if reason is not None:
-                    self._finish_row(i, r, reason)
+            with S.span(S.STEP_EMIT):
+                for i, r in enumerate(self.slots):
+                    if r is None or r.status is not Status.RUNNING:
+                        continue    # PREFILLING rows own no decode token
+                    tok = int(new_tok[i])
+                    r.generated.append(tok)
+                    self._last_tok[i] = tok
+                    tokens_emitted += 1
+                    if obs is not None:
+                        r.mark("token", self.step_idx, t_now)
+                        obs.generated.inc()
+                        prev = self._tok_t[i]
+                        if prev > 0.0:
+                            obs.inter_token.observe(t_now - prev)
+                        self._tok_t[i] = t_now
+                    reason = r.finish_reason_for(tok)
+                    if reason is not None:
+                        self._finish_row(i, r, reason)
         if self._uses_chunks:
             # AFTER the token loop: a sequence whose last chunk landed
             # this step gets token 0 from the chunk logits and decodes
             # its first real token NEXT step — this step's batch logits
             # for its row predate the transition
             t0 = pc()
-            self._process_prefill_results()
+            with S.span(S.STEP_PREFILL_RESULTS):
+                self._process_prefill_results()
             prefill_wall += pc() - t0
         if self.fleet is not None:
             t0 = pc()
@@ -1766,8 +1774,6 @@ class ServingEngine:
         out: Dict[str, float] = {}
         if self.obs is not None:
             out.update(self.obs.registry.snapshot())
-            if self.obs.tracer is not None:
-                out["trace_spans_count"] = float(self.obs.tracer.added)
             if self.obs.drift is not None:
                 out.update(self.obs.drift.report().as_metrics())
         out["steps_count"] = float(self.step_idx)
@@ -1791,16 +1797,6 @@ class ServingEngine:
                     self.fleet.telemetry.summary()).items():
                 out[f"fleet_{k}"] = float(0.0 if v is None else v)
         return schema.StatsDict(out)
-
-    def export_trace(self, path: str) -> str:
-        """Write the pipeline span trace as Chrome trace-event JSON
-        (open in Perfetto / chrome://tracing).  Requires observability
-        with spans enabled."""
-        if self._obs_obj is None or self._obs_obj.tracer is None:
-            raise RuntimeError(
-                "no span tracer — construct the engine with "
-                "observability=True (or ObsConfig(spans=True))")
-        return self._obs_obj.tracer.export(path)
 
     def drift_report(self):
         """The perfmodel drift monitor's measured-vs-predicted

@@ -105,13 +105,13 @@ def test_two_instances_same_name_is_self_edge():
 def test_hold_time_outlier_recorded_not_fatal():
     w = LockWitness()
     w.hold_threshold_s = 0.01
-    (lk,) = _locks(w, "SpanTracer._lock")
+    (lk,) = _locks(w, "MetricsRegistry._lock")
     with lk:
         time.sleep(0.03)
     rep = w.report()
     assert len(rep["hold_outliers"]) == 1
     out = rep["hold_outliers"][0]
-    assert out["lock"] == "SpanTracer._lock" and out["held_s"] > 0.01
+    assert out["lock"] == "MetricsRegistry._lock" and out["held_s"] > 0.01
     w.assert_clean()                     # outliers are not fatal
 
 

@@ -1,8 +1,7 @@
 """Unified observability layer (repro.obs): metrics registry, stats-key
-schema + compat shim, request lifecycle timelines, pipeline span export
-(Chrome trace-event round trip), and the perfmodel drift monitor on a
-skewed-worker scenario."""
-import json
+schema + compat shim, request lifecycle timelines, and the perfmodel
+drift monitor on a skewed-worker scenario.  The hot path's profiler
+spans are tested in test_spans.py."""
 import os
 import sys
 import threading
@@ -11,9 +10,8 @@ import numpy as np
 import pytest
 
 from repro.models import model as M
-from repro.obs import (LEGACY_ALIASES, MetricsRegistry, ObsConfig, SpanTracer,
-                       StatsDict, assert_conforms, check_key, normalize,
-                       timeline)
+from repro.obs import (LEGACY_ALIASES, MetricsRegistry, ObsConfig, StatsDict,
+                       assert_conforms, check_key, normalize, timeline)
 from repro.serving.engine import ServingEngine
 from repro.serving.request import Request
 
@@ -159,36 +157,6 @@ def test_timeline_derivations():
 
 
 # --------------------------------------------------------------------------- #
-# span tracer
-# --------------------------------------------------------------------------- #
-
-def test_span_tracer_ring_and_chrome(tmp_path):
-    tr = SpanTracer(ring=4)
-    for i in range(10):
-        tr.add(f"s{i}", "cat", f"trk{i % 2}", tr.t0 + i, tr.t0 + i + 0.5,
-               {"i": i})
-    assert tr.added == 10
-    assert tr.dropped == 6
-    sp = tr.spans()
-    assert [s["name"] for s in sp] == ["s6", "s7", "s8", "s9"]
-    assert sp[0]["ts_s"] == pytest.approx(6.0)
-    assert sp[0]["dur_s"] == pytest.approx(0.5)
-    path = tr.export(str(tmp_path / "t.json"))
-    with open(path) as f:
-        doc = json.load(f)
-    assert doc["otherData"]["dropped_spans"] == 6
-    xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
-    metas = [e for e in doc["traceEvents"] if e["ph"] == "M"]
-    assert len(xs) == 4
-    # every X event's track resolves to a thread_name metadata record
-    names = {e["tid"]: e["args"]["name"] for e in metas
-             if e["name"] == "thread_name"}
-    assert {names[e["tid"]] for e in xs} == {"trk0", "trk1"}
-    assert xs[0]["ts"] == pytest.approx(6e6) and xs[0]["dur"] == \
-        pytest.approx(5e5)
-
-
-# --------------------------------------------------------------------------- #
 # end-to-end: serving engine with observability on
 # --------------------------------------------------------------------------- #
 
@@ -200,7 +168,7 @@ def _mk_reqs(rng, cfg, n, max_new=4):
                     max_new_tokens=max_new) for i in range(n)]
 
 
-def test_serving_engine_metrics_and_timeline(rng, key, tmp_path):
+def test_serving_engine_metrics_and_timeline(rng, key):
     cfg = tiny_cfg("granite-3-8b")
     params = M.init_params(key, cfg)
     eng = ServingEngine(params, cfg, batch=4, cache_len=48,
@@ -227,7 +195,6 @@ def test_serving_engine_metrics_and_timeline(rng, key, tmp_path):
         # legacy stats surfaces ride along under namespace prefixes
         assert m["hotpath_dispatch_s"] > 0.0
         assert m["hotpath_steps_count"] >= 1.0
-        assert m["trace_spans_count"] > 0.0
         assert m["steps_count"] == float(eng.step_idx)
         # drift monitor is present (still calibrating — short run)
         assert "drift_calibrated_count" in m
@@ -251,37 +218,6 @@ def test_serving_engine_metrics_and_timeline(rng, key, tmp_path):
         assert [e[0] for e in ev].count("token") == 3
         with pytest.raises(KeyError):
             eng.request_timeline(999)
-
-        # -- Chrome trace-event export round trip ---------------------- #
-        path = eng.export_trace(str(tmp_path / "trace.json"))
-        with open(path) as f:
-            doc = json.load(f)
-        xs = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
-        assert xs, "trace export produced no spans"
-        for e in xs:
-            assert e["ts"] >= 0 and e["dur"] >= 0 and e["name"]
-        steps = {e["args"]["step"]: e for e in xs if e["cat"] == "step"}
-        rtts = [e for e in xs if e["cat"] == "r-rtt"]
-        assert steps and rtts
-        # every R-Part round trip nests inside its decode step's span
-        eps = 1e-3   # µs rounding slack
-        for e in rtts:
-            s = steps[e["args"]["step"]]
-            assert e["ts"] >= s["ts"] - eps
-            assert e["ts"] + e["dur"] <= s["ts"] + s["dur"] + eps
-        # within one (step, micro-batch) the layer/phase chain is
-        # sequential: sorted by start time it must advance monotonically
-        by_mb = {}
-        for e in rtts:
-            by_mb.setdefault((e["args"]["step"], e["args"]["mb"]),
-                             []).append(e)
-        assert any(len(v) > 1 for v in by_mb.values())
-        for chain in by_mb.values():
-            chain.sort(key=lambda e: e["ts"])
-            lp = [(e["args"]["layer"], e["args"]["phase"]) for e in chain]
-            assert lp == sorted(lp), lp
-        # R-worker busy windows are on their own tracks
-        assert any(e["cat"] == "r-worker" for e in xs)
     finally:
         eng.close()
 
@@ -330,7 +266,7 @@ def test_observability_off_and_toggle(rng, key):
     for r in _mk_reqs(rng, cfg, 2, max_new=3):
         eng.submit(r)
     eng.run(max_steps=50)
-    # off: no registry, no tracer, no drift — but metrics() still works
+    # off: no registry, no drift — but metrics() still works
     m = eng.metrics()
     assert_conforms(m)
     assert "ttft_s_p50" not in m
@@ -338,8 +274,6 @@ def test_observability_off_and_toggle(rng, key):
     assert eng.request_timeline(0) == []     # no events recorded
     with pytest.raises(RuntimeError):
         eng.set_observability(True)
-    with pytest.raises(RuntimeError):
-        eng.export_trace("/dev/null")
     with pytest.raises(RuntimeError):
         eng.drift_report()
 
