@@ -369,6 +369,12 @@ class RWorker(threading.Thread):
         self.outq: "queue.Queue" = queue.Queue()  # legacy (FIFO) replies
         self._jit_cache: Dict[Tuple[str, int], Any] = {}
         self.busy_time = 0.0
+        # the paged decode kernel's walk, counted from host lengths: grid
+        # blocks it copies and computes, and blocks in its grid (only
+        # this thread writes them; the engine reads them between steps)
+        self.paged_blocks_run = 0
+        self.paged_blocks_grid = 0
+        self._walk: Dict[int, Tuple[int, int]] = {}   # mb -> (run, grid)
         self._killed = False
         # chaos.FaultPlan (or None): fault-injection hooks in _run_one
         # and the paged allocator; a single `is None` test when off
@@ -605,15 +611,21 @@ class RWorker(threading.Thread):
         (``r_in["active"]`` False: released slots, rows mid-chunked-
         prefill) are excluded from the grow AND the length bump — their
         allocator bookkeeping belongs to the prefill path."""
+        from repro.kernels.paged_attention import blocks_walked
         from repro.serving import paged_cache as PC
         mb = layer // self.cfg.num_layers
         alloc = self.allocators[mb]
         with S.span(S.R_GROW):
             if layer == self._first_paged_key(mb):
                 act = r_in.get("active")
-                alloc.ensure_lengths(np.asarray(r_in["lengths"]) + 1,
+                lens = np.asarray(r_in["lengths"])
+                alloc.ensure_lengths(lens + 1,
                                      mask=None if act is None
                                      else np.asarray(act))
+                pool = self.state[layer]
+                self._walk[mb] = (blocks_walked(lens, 1, pool["k"],
+                                                alloc.max_pages)
+                                  if "k" in pool else (0, 0))
                 # CoW clones computed once on the shared allocator;
                 # every paged layer of this step applies them to its
                 # OWN pool below (the block table already points at
@@ -624,6 +636,9 @@ class RWorker(threading.Thread):
                 self.state[layer] = PC.clone_pool_pages(self.state[layer],
                                                         clones)
             tables = alloc.tables_device()
+        run, grid = self._walk[mb]
+        self.paged_blocks_run += run
+        self.paged_blocks_grid += grid
         r_out, new_pool = self._paged_fn()(r_in, self.state[layer], tables)
         return r_out, new_pool
 
@@ -1486,6 +1501,7 @@ class HeteroPipelineEngine:
         self.prefill_results = []
         chunk_carries: Dict[int, Any] = {}
         active = (self.num_mb if run_decode else 0) + len(works)
+        walk0 = self._paged_walk()
 
         def dispatch(mb: int, li: int, phase: int, shards) -> None:
             with S.span(S.PIPE_DISPATCH, mb=mb, layer=li, phase=phase):
@@ -1727,6 +1743,9 @@ class HeteroPipelineEngine:
             self.prefill_results.append(wk)
         stats["step_s"] = pc() - t_step0
         stats["emit_mean_s"] = sum(emit_at) / self.num_mb
+        walk1 = self._paged_walk()
+        stats["paged_blocks_run_count"] = float(walk1[0] - walk0[0])
+        stats["paged_blocks_grid_count"] = float(walk1[1] - walk0[1])
         self.last_step_stats = stats
         for k, v in stats.items():
             self.step_stats[k] = self.step_stats.get(k, 0.0) + v
@@ -1856,6 +1875,12 @@ class HeteroPipelineEngine:
             self.step_stats[k] = self.step_stats.get(k, 0.0) + v
         self.step_stats["steps"] = self.step_stats.get("steps", 0.0) + 1.0
         return outs
+
+    def _paged_walk(self) -> Tuple[int, int]:
+        """The R-workers' paged decode walk so far: (grid blocks run,
+        grid blocks), read while no R-Part work is in flight."""
+        return (sum(w.paged_blocks_run for w in self.workers),
+                sum(w.paged_blocks_grid for w in self.workers))
 
     def reset_step_stats(self) -> None:
         self.step_stats = {}

@@ -136,6 +136,65 @@ def test_paged_verify_kernel_unmapped_row_is_zero(rng):
         assert float(jnp.abs(o[0]).max()) > 0.0
 
 
+# ---------------------------------------------------------------------------
+# the paged kernel's walk in multi-page blocks: the interpreter's fresh
+# memory is NaN, so a slot that is read without having been copied fails
+# ---------------------------------------------------------------------------
+def _walk_case(rng, t, ppb, page, mp, num_pages):
+    """Rows at the block edges, a fully unmapped row, a short row, and a
+    row (a degraded one, whose table stopped growing) whose last needed
+    block holds a mapped slot and then an unmapped one inside the
+    length."""
+    tok = ppb * page
+    lengths = np.asarray([tok - 1, tok, tok + 1, 13, 0,
+                          2 * tok + page + 1 - t], np.int32)
+    tables = np.full((len(lengths), mp), -1, np.int32)
+    perm = list(rng.permutation(num_pages))
+    for row, n in enumerate(lengths):
+        need = -(-(int(n) + t) // page)
+        if row == 3:
+            need = 0
+        elif row == 5:
+            assert need == 2 * ppb + 2
+            need -= 1
+        for k in range(min(need, mp)):
+            tables[row, k] = perm.pop()
+    return jnp.asarray(tables), jnp.asarray(lengths)
+
+
+@pytest.mark.parametrize("t", [1, 3, 5])
+@pytest.mark.parametrize("kw", FEATS)
+@pytest.mark.parametrize("ppb", [2, 4])
+def test_paged_kernel_multi_page_blocks_vs_oracle(t, kw, ppb, rng):
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels import paged_attention as PA
+    hkv, g, dh, page = 2, 2, 8, 4
+    mp = 3 * ppb + 1                         # MP not a multiple of ppb
+    num_pages = 6 * mp
+    pk = _mk(rng, num_pages, page, hkv, dh)
+    pv = _mk(rng, num_pages, page, hkv, dh)
+    tables, lengths = _walk_case(rng, t, ppb, page, mp, num_pages)
+    q = _mk(rng, len(lengths), t, hkv * g, dh)
+    o = PA.paged_verify_attention(q, pk, pv, tables, lengths,
+                                  pages_per_block=ppb,
+                                  interpret=pltpu.InterpretParams(), **kw)
+    o_ref = R.paged_verify_attention_ref(q, pk, pv, tables, lengths, **kw)
+    assert bool(jnp.isfinite(o).all())
+    np.testing.assert_allclose(o, o_ref, atol=3e-5)
+    assert float(jnp.abs(o[3]).max()) == 0.0
+
+
+@pytest.mark.parametrize("shape,ppb", [
+    ((16, 8, 128, 2, 576), 8),       # qwen3-8b decode cell: 256 KiB blocks
+    ((16, 32, 128, 2, 64), 2),       # llama-7b, MHA
+    ((16, 8, 128, 2, 4), 4),         # a short table caps the block
+    ((256, 8, 128, 2, 64), 1),       # a page above the budget: one a step
+])
+def test_pages_per_block_from_shapes(shape, ppb):
+    from repro.kernels import paged_attention as PA
+    assert PA.choose_pages_per_block(*shape) == ppb
+
+
 def test_verify_refs_match_per_position_decode(rng):
     """Row-by-row oracle: position t of the verify output equals a decode
     call with lengths + t, for dense fp and int8 storage."""

@@ -11,6 +11,7 @@ from repro.core.hetero import ColocatedEngine, HeteroPipelineEngine
 from repro.kernels import ops
 from repro.kernels import ref as KR
 from repro.models import model as M
+from repro.obs import schema
 
 B, S, GEN = 4, 12, 5
 RAGGED = (5, 12, 3, 9)
@@ -51,6 +52,45 @@ def test_paged_matches_dense_and_colocated_ragged(page, rng, key):
                             paged_kv=True, page_size=page)
     assert float(jnp.abs(paged - dense).max()) < 2e-4
     assert float(jnp.abs(paged - ref_logits).max()) < 2e-4
+
+
+def test_paged_walk_counters_match_closed_form(rng, key):
+    """The decode step counts the paged kernel's walk from host lengths:
+    blocks run = sum over calls and rows of ceil((len + 1) / (ppb *
+    page)), blocks in the grid = rows x ceil(MP / ppb) per call.  Row 1
+    crosses a block boundary during the steps."""
+    from repro.kernels.paged_attention import choose_pages_per_block
+    cfg = tiny_cfg("granite-3-8b")
+    params = M.init_params(key, cfg)
+    page, gen = 4, 5
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, S + gen)))
+    plens = np.asarray(RAGGED, np.int32)
+    eng = HeteroPipelineEngine(params, cfg, batch=B, cache_len=S + gen,
+                               num_r_workers=2, num_microbatches=2,
+                               kv_chunk=8, paged_kv=True, page_size=page)
+    h = B // 2
+    try:
+        eng.load_prefill(0, tokens[:h, :S], jnp.asarray(plens[:h]))
+        eng.load_prefill(1, tokens[h:, :S], jnp.asarray(plens[h:]))
+        w = eng.workers[0]
+        layers = len({k % cfg.num_layers for k in w.paged_keys})
+        pool = w.state[min(w.paged_keys)]["k"]
+        mp = -(-(S + gen) // page)
+        ppb = choose_pages_per_block(page, cfg.num_kv_heads, cfg.head_dim,
+                                     pool.dtype.itemsize, mp)
+        assert 1 < -(-mp // ppb)              # the grid has several blocks
+        for t in range(gen):
+            tok = tokens[:, S + t:S + t + 1]
+            eng.decode_step([tok[:h], tok[h:]])
+        stats = schema.normalize(eng.step_stats)
+    finally:
+        eng.close()
+    lens = plens[None, :] + np.arange(gen)[:, None]          # [step, row]
+    run = layers * int((-(-(lens + 1) // (ppb * page))).sum())
+    grid = layers * gen * B * -(-mp // ppb)
+    assert stats["paged_blocks_run_count"] == run
+    assert stats["paged_blocks_grid_count"] == grid
+    assert run < grid
 
 
 # NOTE: the former test_paged_int8_matches_dense_int8 (§5.2 composition:

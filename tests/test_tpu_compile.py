@@ -130,3 +130,17 @@ def test_dense_decode_compiles_for_v5e(one_chip, width):
             q, k, v, p, n, use_kernel="pallas"),
         ((B, hq, DH), BF16), ((B, S, hkv, DH), BF16),
         ((B, S, hkv, DH), BF16), ((B, S), I32), ((B,), I32))
+
+
+def test_paged_decode_compiles_for_v5e_at_the_decode_cell(one_chip):
+    """The decode cell's R-Part call: 2 rows of 576 page slots (cache
+    9,216 tokens) over a pool of 1,152 pages, qwen3-8b widths (32/8
+    heads): the multi-page blocks and their double buffers fit."""
+    rows, mp = 2, 9216 // PAGE
+    pool = ((rows * mp, PAGE, 8, DH), BF16)
+    _compile(
+        one_chip,
+        lambda q, k, v, t, n: ops.paged_decode_attention(
+            q, k, v, t, n, use_kernel="pallas"),
+        ((rows, 32, DH), BF16), pool, pool, ((rows, mp), I32),
+        ((rows,), I32))
