@@ -342,7 +342,7 @@ def main(argv=None) -> int:
         peaks = json.load(f)[jax.devices()[0].device_kind]
     metrics = [m for m in bench["per_layer"]
                if args.workload in m.get("workloads", [args.workload])]
-    got = traced_run(run.specmod.load(cell["config"]),
+    got = traced_run(run.family.load_config(cell["config"]),
                      run.traffic.load(cell["traffic"]), args.seed,
                      args.seconds, metrics, peaks)
     print(json.dumps(got["result"]), flush=True)

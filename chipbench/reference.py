@@ -9,8 +9,9 @@ One departure, shared with the program and noted in the configuration
 file: RoPE rotates the pairs ``(2i, 2i+1)``.
 
 It runs once the measured window has closed and the program's state is
-freed, layer by layer: each layer's weights are made anew from the seed
-(``weights.layer_weights``), applied to every sequence, and dropped.
+freed, layer by layer (``layer_by_layer``): each layer's weights are
+made anew from the seed (``weights.layer_weights``), applied to every
+sequence, and dropped.
 Matrix products run in float32 at ``precision="highest"``; attention
 runs over blocks of queries so that no full score matrix is held.
 
@@ -21,7 +22,7 @@ would compute.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence
+from typing import Callable, Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -127,22 +128,61 @@ def _served_gap(logits, tokens):
 _head_jit = jax.jit(_head_logits, static_argnums=(0, 1))
 
 
-def hidden_states(spec: Spec, seed: int, seqs: Sequence[np.ndarray],
-                  modes: Sequence[str] = ("f32",)) -> Dict[str, List]:
-    """Last-layer hidden states [T_pad, d] of every sequence, per mode."""
+def layer_by_layer(seqs: Sequence[np.ndarray], modes: Sequence[str],
+                   layers: int, embed: Callable, layer_weights: Callable,
+                   block: Callable) -> Dict[str, List]:
+    """Last-layer hidden states [T_pad, d] of every sequence, per mode.
+
+    Sequences are padded to a multiple of ``BUCKET``; ``embed(tokens)``
+    gives their first hidden states.  Each layer's weights,
+    ``layer_weights(li)``, are made once, applied by ``block(li, mode,
+    w, h)`` to every sequence in every mode, and dropped, so no more
+    than one layer's weights are held."""
     padded = []
     for s in seqs:
         n = -(-len(s) // BUCKET) * BUCKET
         buf = np.zeros((n,), np.int32)
         buf[:len(s)] = s
         padded.append(jnp.asarray(buf))
-    hs = {m: [W.embed_rows(spec, seed, p) for p in padded] for m in modes}
-    for li in range(spec.layers):
-        lw = W.layer_weights(spec, seed, li)
+    hs = {m: [embed(p) for p in padded] for m in modes}
+    for li in range(layers):
+        lw = layer_weights(li)
         for m in modes:
-            hs[m] = [_block_jit(spec, m, lw, h) for h in hs[m]]
+            hs[m] = [block(li, m, lw, h) for h in hs[m]]
         del lw
     return hs
+
+
+def hidden_states(spec: Spec, seed: int, seqs: Sequence[np.ndarray],
+                  modes: Sequence[str] = ("f32",)) -> Dict[str, List]:
+    """Last-layer hidden states [T_pad, d] of every sequence, per mode."""
+    return layer_by_layer(
+        seqs, modes, spec.layers, lambda p: W.embed_rows(spec, seed, p),
+        lambda li: W.layer_weights(spec, seed, li),
+        lambda li, m, w, h: _block_jit(spec, m, w, h))
+
+
+def served_gaps(seqs: Sequence[np.ndarray], served_from: Sequence[int],
+                hs: Dict[str, List], head: Callable, control: bool):
+    """The gaps ``gaps`` returns, from the last hidden states ``hs`` of
+    every mode (``"f32"``, and ``"fp8"`` with ``control``) and
+    ``head(mode, h)``, which maps hidden-state rows to logits."""
+    served, ctrl = [], []
+    for i, s in enumerate(seqs):
+        pos = np.arange(served_from[i] - 1, len(s) - 1)
+        n = len(pos)
+        # pad the rows read to a multiple of ROWS so runs share programs
+        pos = np.concatenate([pos, np.full(-n % ROWS, pos[-1])])
+        rows = jnp.asarray(pos)
+        toks = jnp.asarray(np.asarray(s, np.int32)[pos + 1])
+        ref = head("f32", hs["f32"][i][rows])
+        served.append(np.asarray(_served_gap(ref, toks))[:n])
+        if control:
+            c = head("fp8", hs["fp8"][i][rows])
+            ctrl.append(np.asarray(_served_gap(
+                ref, jnp.argmax(c, axis=1).astype(jnp.int32)))[:n])
+        del ref
+    return (served, ctrl) if control else served
 
 
 def gaps(spec: Spec, seed: int, seqs: Sequence[np.ndarray],
@@ -158,19 +198,5 @@ def gaps(spec: Spec, seed: int, seqs: Sequence[np.ndarray],
     modes = ("f32", "fp8") if control else ("f32",)
     hs = hidden_states(spec, seed, seqs, modes)
     hw = W.head_weights(spec, seed)
-    served, ctrl = [], []
-    for i, s in enumerate(seqs):
-        pos = np.arange(served_from[i] - 1, len(s) - 1)
-        n = len(pos)
-        # pad the rows read to a multiple of ROWS so runs share programs
-        pos = np.concatenate([pos, np.full(-n % ROWS, pos[-1])])
-        rows = jnp.asarray(pos)
-        toks = jnp.asarray(np.asarray(s, np.int32)[pos + 1])
-        ref = _head_jit(spec, "f32", hw, hs["f32"][i][rows])
-        served.append(np.asarray(_served_gap(ref, toks))[:n])
-        if control:
-            c = _head_jit(spec, "fp8", hw, hs["fp8"][i][rows])
-            ctrl.append(np.asarray(_served_gap(
-                ref, jnp.argmax(c, axis=1).astype(jnp.int32)))[:n])
-        del ref
-    return (served, ctrl) if control else served
+    return served_gaps(seqs, served_from, hs,
+                       lambda m, x: _head_jit(spec, m, hw, x), control)

@@ -5,9 +5,11 @@
 
 The cell, its configuration and its traffic mix are found by name from
 ``BENCHMARK.json``; the configuration is ``chipbench/configs/<config>.json``
-and the mix ``chipbench/traffic/<traffic>.json``.  In order, a run finds
-the chip (and fails without one), turns on the compile cache in the
-checkout, makes the weights on the device from the seed, builds the
+and the mix ``chipbench/traffic/<traffic>.json``.  The configuration's
+family, ``chipbench/archs/<family>.py`` (``family.py``), gives its
+sizes, weights, program configuration and reference.  In order, a run
+finds the chip (and fails without one), turns on the compile cache in
+the checkout, makes the weights on the device from the seed, builds the
 hetero serving engine, warms up the shapes the mix uses, builds the
 state the mix needs, measures for ``--seconds``, checks the served
 tokens against the float32 reference, and prints one JSON line last.
@@ -40,7 +42,7 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
-import spec as specmod  # noqa: E402
+import family  # noqa: E402
 import traffic  # noqa: E402
 
 # the persistent compile cache lives at a fixed path in the checkout
@@ -260,15 +262,14 @@ def pick_checked(srv, chk: dict, seed: int) -> List[int]:
     return [longest] + [int(x) for x in pick]
 
 
-def compare(spec, seed, srv, rids, control=False):
-    import reference
+def compare(fam, spec, seed, srv, rids, control=False):
     seqs, starts = [], []
     for rid in rids:
         r = srv.reqs[rid]
         seqs.append(np.concatenate([np.asarray(r.prompt, np.int32),
                                     np.asarray(r.generated, np.int32)]))
         starts.append(r.prompt_len)
-    return reference.gaps(spec, seed, seqs, starts, control=control)
+    return fam.gaps(spec, seed, seqs, starts, control=control)
 
 
 # --------------------------------------------------------------------------
@@ -303,20 +304,21 @@ def execute(spec, mix: dict, seed: int, seconds: float, trace: bool,
     import jax
     from repro.launch.cache import enable_compile_cache
     import system
-    import weights
 
+    fam = family.load(spec.family)
     if cache:
         enable_compile_cache()
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     counter = CompileCounter()
     devs = jax.devices()
     dev = devs[0]
-    cfg = system.model_config(spec)
+    cfg = fam.model_config(spec)
     t0 = time.perf_counter()
-    params = weights.program_params(spec, seed)
+    params = fam.program_params(spec, seed)
     jax.block_until_ready(params)
     t1 = time.perf_counter()
-    eng = system.build_engine(params, cfg, mix["engine"], seed)
+    build = getattr(fam, "build_engine", system.build_engine)
+    eng = build(params, cfg, mix["engine"], seed)
     del params
     phases = {"setup_to_weights_s": t0 - T_PROC, "weights_s": t1 - t0,
               "engine_s": time.perf_counter() - t1}
@@ -376,7 +378,7 @@ def execute(spec, mix: dict, seed: int, seconds: float, trace: bool,
     rids = pick_checked(srv, chk, seed)
     t_ref = time.perf_counter()
     if rids:
-        res = compare(spec, seed, srv, rids, control=control)
+        res = compare(fam, spec, seed, srv, rids, control=control)
         served, ctrl = res if control else (res, None)
         n_tok = int(sum(len(g) for g in served))
         max_gap = float(max(g.max() for g in served))
@@ -459,7 +461,7 @@ def main(argv=None) -> int:
     kind = "per_layer" if args.trace else "end_to_end"
     metrics = [m for m in bench[kind]
                if args.workload in m.get("workloads", [args.workload])]
-    spec = specmod.load(cell["config"])
+    spec = family.load_config(cell["config"])
     mix = traffic.load(cell["traffic"])
     result, checks, _ = execute(
         spec, mix, args.seed, args.seconds, bool(args.trace), metrics,

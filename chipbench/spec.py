@@ -1,16 +1,13 @@
-"""A benchmark configuration file, read as the sizes the benchmark needs.
+"""A dense configuration file, read as the sizes the benchmark needs.
 
 The file under ``chipbench/configs/<name>.json`` holds the published
 ``config.json`` keys (with the depth cut listed under ``reduced``) plus
-the benchmark's own notes.  Nothing here imports the program.
+the benchmark's own notes; ``family.load_config`` reads it.  Nothing
+here imports the program.
 """
 from __future__ import annotations
 
-import json
-import os
 from dataclasses import dataclass
-
-HERE = os.path.dirname(os.path.abspath(__file__))
 
 
 @dataclass(frozen=True)
@@ -29,6 +26,7 @@ class Spec:
     qk_norm: bool
     dtype: str
     program_arch: str
+    family: str = "dense"
 
     @property
     def kv_bytes_per_token(self) -> int:
@@ -64,8 +62,3 @@ def from_dict(raw: dict, name: str = "") -> Spec:
         qk_norm=raw.get("model_type") == "qwen3",
         dtype=dtype,
         program_arch=raw["program_arch"])
-
-
-def load(name: str) -> Spec:
-    with open(os.path.join(HERE, "configs", f"{name}.json")) as f:
-        return from_dict(json.load(f), name)

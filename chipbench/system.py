@@ -2,31 +2,17 @@
 
 The benchmark takes from the program its serving engine and the counters
 that engine exposes; everything else (weights, traffic, the reference)
-is the benchmark's own.  ``src/`` is put on the import path here.
+is the benchmark's own, and the program's configuration comes from the
+configuration's family (``family.py``).  ``src/`` is put on the import
+path here.
 """
 from __future__ import annotations
 
-import dataclasses
 import os
 import sys
 
-from spec import Spec
-
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "src"))
-
-
-def model_config(spec: Spec):
-    """The program's ModelConfig for ``spec``: the registered architecture
-    with every size taken from the configuration file."""
-    from repro.core.config import get_arch
-    return dataclasses.replace(
-        get_arch(spec.program_arch), num_layers=spec.layers,
-        d_model=spec.d_model, num_heads=spec.heads,
-        num_kv_heads=spec.kv_heads, head_dim=spec.head_dim,
-        d_ff=spec.d_ff, vocab_size=spec.vocab, rope_theta=spec.rope_theta,
-        norm_eps=spec.norm_eps, tie_embeddings=spec.tied,
-        qk_norm=spec.qk_norm, dtype=spec.dtype)
 
 
 def pool_pages(eng: dict) -> int:

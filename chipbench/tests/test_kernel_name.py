@@ -9,8 +9,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+import family
 import run
-import spec as specmod
 
 ROWS, PAGE, PAGES_PER_ROW = 4, 16, 16
 
@@ -19,7 +19,7 @@ def test_reader_finds_the_compiled_paged_kernel():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from repro.kernels import ops
-    sp = specmod.load("qwen3-8b")
+    sp = family.load_config("qwen3-8b")
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])

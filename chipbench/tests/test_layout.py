@@ -7,8 +7,8 @@ import re
 
 import pytest
 
+import family
 import run
-import spec as specmod
 import traffic
 from devtrace import Trace
 
@@ -25,9 +25,11 @@ def test_names_and_files(bench):
     for c in bench["configs"]:
         assert NAME.match(c["name"])
         assert os.path.isfile(os.path.join(run.ROOT, c["file"]))
-        sp = specmod.load(c["name"])
+        sp = family.load_config(c["name"])
         with open(os.path.join(run.ROOT, c["file"])) as f:
             raw = json.load(f)
+        # the file BENCHMARK.json names is the one its family reads
+        assert family.load(sp.family).spec(raw, c["name"]) == sp
         assert sorted(c["reduced"]) == sorted(raw["reduced"])
         assert sp.layers < raw["published"]["num_hidden_layers"]
     for w in bench["workloads"]:
@@ -66,7 +68,7 @@ def _made_up_run(spec):
 
 
 def test_readers(bench):
-    sp = specmod.load("qwen3-8b")
+    sp = family.load_config("qwen3-8b")
     for m in bench["per_layer"]:
         read = run.load_reader(m["name"]).read
         empty = run.RunData(spec=sp, mix={}, peaks={})
